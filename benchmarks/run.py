@@ -40,10 +40,14 @@ def _row(name, us, derived=""):
 
 
 def _subprocess_env(xla_flags: str) -> dict:
-    """Environment for an acceptance-cell subprocess: fresh XLA flags plus
-    this repo's src/ ahead of any inherited PYTHONPATH entries."""
+    """Environment for an acceptance-cell subprocess: fresh XLA flags, the
+    CPU backend, and this repo's src/ ahead of any inherited PYTHONPATH
+    entries. These cells emulate an accelerator on the host CPU by their
+    own XLA flags, and the parent process already holds the accelerator
+    (one process per chip), so a child must never reach for it."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = xla_flags
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(os.path.dirname(__file__), "..", "src")]
         + [p for p in env.get("PYTHONPATH", "").split(os.pathsep) if p])
@@ -1939,7 +1943,7 @@ SMOKE = [bench_tick_latency, bench_scan_engine, bench_scan_sharded,
          bench_ingest_fastpath]
 
 
-def main() -> None:
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--smoke", action="store_true",
@@ -1961,12 +1965,15 @@ def main() -> None:
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "") +
             f" --xla_force_host_platform_device_count={args.host_devices}")
+    from repro import compat
+    compat.enable_compile_cache()
     benches = SMOKE if args.smoke else ALL
     if args.smoke:
         args.quick = True
     # --only accepts "|"- or ","-separated name fragments
     wanted = [w for w in args.only.replace(",", "|").split("|") if w]
     print("name,us_per_call,derived")
+    failed = []
     for bench in benches:
         if wanted and not any(w in bench.__name__ for w in wanted):
             continue
@@ -1974,6 +1981,7 @@ def main() -> None:
             bench(quick=args.quick)
         except Exception as e:  # a failing table must not hide the others
             _row(bench.__name__, -1.0, f"ERROR {type(e).__name__}: {e}")
+            failed.append(bench.__name__)
     if args.json:
         import jax
         out = {
@@ -1987,7 +1995,11 @@ def main() -> None:
         with open(args.json, "w") as f:
             json.dump(out, f, indent=2)
         print(f"# wrote {args.json}", flush=True)
+    if failed:
+        print(f"# failed tables: {', '.join(failed)}", file=sys.stderr)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -8,11 +8,14 @@ import time
 
 import numpy as np
 
+from repro import compat
 from repro.core import PipelineConfig
 from repro.core.reward import energy_reward_spec
 from repro.runtime.predictor import ActionSpace, Predictor, linear_policy
 from repro.runtime.receivers import SimulatedDevice
 from repro.runtime.system import PerceptaSystem, SourceSpec
+
+compat.enable_compile_cache()
 
 print("=== Percepta cloud mode: environment-count scaling ===")
 print(f"{'envs':>6s} {'tick ms':>9s} {'us/env':>8s} {'env-ticks/s':>12s}")

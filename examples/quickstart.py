@@ -7,9 +7,12 @@ Run: PYTHONPATH=src python examples/quickstart.py
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compat
 from repro.core import PerceptaPipeline, PipelineConfig
 from repro.core.frame import make_raw_window
 from repro.core.reward import RewardSpec, RewardTerm
+
+compat.enable_compile_cache()
 
 E, S, M, T = 4, 3, 48, 16          # envs, streams, raw samples, ticks
 cfg = PipelineConfig(n_envs=E, n_streams=S, n_ticks=T, tick_s=60.0,

@@ -55,11 +55,14 @@ Run: PYTHONPATH=src python examples/serve_edge.py \
           scan_fused_decide_sharded|...|fused]
 """
 import argparse
+import os
+import tempfile
 import time
 
 import jax
 import numpy as np
 
+from repro import compat
 from repro.configs.registry import get_config
 from repro.core import PipelineConfig
 from repro.core.reward import energy_reward_spec
@@ -70,6 +73,8 @@ from repro.runtime.predictor import ActionSpace, Predictor
 from repro.runtime.receivers import SimulatedDevice
 from repro.runtime.system import PerceptaSystem, SourceSpec
 from repro.serve.engine import Request, ServeEngine
+
+compat.enable_compile_cache()
 
 # --- the ad-hoc serving model: a real (reduced-config) transformer ---------
 cfg_lm = get_config("qwen3-0.6b:smoke")
@@ -123,7 +128,8 @@ pred = Predictor("rglru",
                  ActionSpace(np.array([-1., -1.]), np.array([1., 1.])),
                  E, pcfg.n_features, db=None, replay_capacity=256)
 print(f"decision policy: {pred.model.certificate.describe()}")
-db = LogDB("/tmp/percepta_serve_db", salt="opeva")
+db = LogDB(os.path.join(tempfile.gettempdir(), "percepta_serve_db"),
+           salt="opeva")
 hub = ForwarderHub([Forwarder("hvac", "mqtt", [0]),
                     Forwarder("ev-charger", "amqp", [1])])
 system = PerceptaSystem([f"bldg-{i}" for i in range(E)], sources, pcfg, pred,

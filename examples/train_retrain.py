@@ -25,15 +25,20 @@ Two ways to retrain a running Percepta deployment:
 Run: PYTHONPATH=src python examples/train_retrain.py [--windows 30]
 """
 import argparse
+import os
 import shutil
+import tempfile
 
 import numpy as np
 
+from repro import compat
 from repro.core import PipelineConfig
 from repro.core.reward import energy_reward_spec
 from repro.runtime.predictor import ActionSpace, Predictor, linear_policy
 from repro.runtime.receivers import SimulatedDevice
 from repro.runtime.system import PerceptaSystem, SourceSpec
+
+compat.enable_compile_cache()
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--windows", type=int, default=30)
@@ -43,7 +48,7 @@ args = ap.parse_args()
 # APPLIED (and hence checkpointed) before the simulated crash
 assert args.windows >= 4 * args.scan_k, "--windows must be >= 4 * --scan-k"
 
-CKDIR = "/tmp/percepta_online_ckpt"
+CKDIR = os.path.join(tempfile.gettempdir(), "percepta_online_ckpt")
 shutil.rmtree(CKDIR, ignore_errors=True)
 
 

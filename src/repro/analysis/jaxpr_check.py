@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compat
 from repro.analysis.contracts import (
     ContractViolation, Violation, TAG_ENV, TAG_MASK, TAG_TIME,
 )
@@ -128,8 +129,8 @@ _COLLECTIVES = frozenset(
      "pbroadcast", "pgather", "pdot"])
 
 _CALLBACKS = frozenset(
-    ["pure_callback", "io_callback", "debug_callback", "callback",
-     "outside_call", "host_callback_call", "python_callback"])
+    ["pure_callback", "io_callback", "debug_callback", "debug_print",
+     "callback", "outside_call", "host_callback_call", "python_callback"])
 
 # higher-order prims handled structurally
 _SUBJAXPR_KEYS = ("jaxpr", "call_jaxpr", "fun_jaxpr")
@@ -137,8 +138,7 @@ _SUBJAXPR_KEYS = ("jaxpr", "call_jaxpr", "fun_jaxpr")
 
 def _src_of(eqn) -> str:
     try:
-        from jax._src import source_info_util
-        return str(source_info_util.summarize(eqn.source_info))
+        return compat.source_summary(eqn)
     except Exception:
         return "<unknown>"
 
@@ -444,9 +444,8 @@ _PALLAS_GRID_CAP = 4096  # max grid points to evaluate index maps over
 
 def _eval_index_map(bm, point):
     """Evaluate one BlockSpec index map at a concrete grid point."""
-    cj = bm.index_map_jaxpr
-    from jax._src.core import eval_jaxpr as _eval
-    res = _eval(cj.jaxpr, cj.consts, *(np.int32(i) for i in point))
+    res = compat.eval_jaxpr(bm.index_map_jaxpr,
+                            *(np.int32(i) for i in point))
     return tuple(int(np.asarray(r)) for r in res)
 
 
@@ -465,7 +464,7 @@ def _prop_pallas(eqn, ins, ctx, loop_depth):
     turns into the conservative spread-all fallback.
     """
     params = eqn.params
-    gm = params["grid_mapping"]
+    gm = compat.pallas_grid_mapping(eqn)
     kernel = _open(params["jaxpr"])
     grid = tuple(gm.grid)
     nouts = _out_ndims(eqn)
@@ -484,8 +483,7 @@ def _prop_pallas(eqn, ins, ctx, loop_depth):
     points = list(np.ndindex(*grid)) if grid else [()]
 
     def block_size(bm, d):
-        b = bm.block_shape[d]
-        return 1 if b is None else int(b)
+        return compat.pallas_block_sizes(bm)[d]
 
     # the env-block index function each instance must agree on, from the
     # env-tagged inputs

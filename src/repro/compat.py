@@ -1,123 +1,114 @@
-"""JAX cross-version compatibility shims.
+"""The JAX API seams this repository routes through one module.
 
-The repo targets the JAX that ships on the edge image (0.4.x today) while
-staying runnable on newer releases. Three API seams moved between 0.4.x and
-0.5+/0.6+, and every call site routes through here instead of branching
-locally:
+The installed JAX is 0.9.0, and this module is written for it alone; it
+holds no branch for another release. Call sites import the seams from
+here instead of spelling them locally, so that the next JAX upgrade
+changes one file:
 
-  * ``AxisType`` / ``Mesh(..., axis_types=...)`` — ``jax.sharding.AxisType``
-    does not exist in 0.4.x and ``Mesh`` only grew the ``axis_types``
-    keyword later. ``make_mesh`` builds a Mesh with explicit-Auto axis
-    types when the installed JAX understands them and plain axes otherwise
-    (0.4.x treats every axis as Auto already, so the semantics match).
-  * ``AbstractMesh`` — 0.4.x takes one ``((name, size), ...)`` shape tuple;
-    newer JAX takes ``(axis_sizes, axis_names)``. ``abstract_mesh`` accepts
-    the new-style arguments and adapts.
-  * ``jax.set_mesh`` — newer JAX's context setter. 0.4.x spells it
-    ``jax.sharding.use_mesh`` (briefly) or just the Mesh's own context
-    manager. ``set_mesh`` returns whichever works.
+  * ``make_mesh`` / ``abstract_mesh`` / ``set_mesh`` / ``shard_map`` —
+    mesh construction (explicit Auto axis types), the ambient mesh and
+    the shard_map entry point with its replication-check keyword.
+  * ``cost_analysis`` — the compiled program's cost properties as one
+    flat dict.
+  * ``eval_jaxpr`` / ``source_summary`` / ``pallas_grid_mapping`` /
+    ``pallas_block_sizes`` — the jaxpr and Pallas internals the contract
+    checker (``repro.analysis.jaxpr_check``) reads.
+  * ``jit_donated`` — ``jax.jit`` with buffer donation, absorbing the
+    donation quirks below.
+  * ``enable_compile_cache`` — the persistent compilation cache every
+    entry point shares.
 
-Donation quirk: some backend/version combinations warn ("Some donated
-buffers were not usable") instead of donating. ``jit_donated`` applies
-``donate_argnums`` and silences that warning so benchmark CSVs stay clean;
-donation is an optimization, never a semantic requirement, in this repo.
+Donation quirk: some backends warn ("Some donated buffers were not
+usable") instead of donating. ``jit_donated`` applies ``donate_argnums``
+and silences that warning so benchmark output stays clean; donation is an
+optimization, never a semantic requirement, in this repo.
 """
 from __future__ import annotations
 
-import contextlib
-import re
+import os
 import warnings
+from pathlib import Path
 
 import jax
+from jax.sharding import AxisType
+
+# <checkout>/.jax_cache: a fixed path (the cache key includes it), listed
+# in .gitignore
+_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
-def _version_tuple() -> tuple:
-    parts = []
-    for p in jax.__version__.split(".")[:3]:
-        m = re.match(r"\d+", p)
-        parts.append(int(m.group(0)) if m else 0)
-    return tuple(parts)
+def enable_compile_cache() -> None:
+    """Turn on JAX's persistent compilation cache for an entry point.
 
-
-JAX_VERSION = _version_tuple()
-
-try:  # JAX >= 0.5-ish
-    from jax.sharding import AxisType  # type: ignore
-except ImportError:  # 0.4.x: no explicit/auto axis-type distinction
-    AxisType = None
-
-
-def mesh_supports_axis_types() -> bool:
-    return AxisType is not None
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here; otherwise the cache lives at
+    ``<checkout>/.jax_cache``. Tests do not call this: a compile made for
+    a described (unattached) chip is written to the cache but cannot be
+    read back without one.
+    """
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    jax.config.update("jax_compilation_cache_dir", str(_CACHE_DIR))
 
 
 def make_mesh(devices, axis_names):
-    """``jax.sharding.Mesh`` with Auto axis types when supported."""
-    if AxisType is not None:
-        return jax.sharding.Mesh(
-            devices, axis_names,
-            axis_types=(AxisType.Auto,) * len(axis_names))
-    return jax.sharding.Mesh(devices, axis_names)
+    """``jax.sharding.Mesh`` with explicit Auto axis types."""
+    return jax.sharding.Mesh(devices, axis_names,
+                             axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def abstract_mesh(axis_sizes, axis_names):
-    """``AbstractMesh`` from (sizes, names) across both signatures."""
-    axis_sizes = tuple(int(s) for s in axis_sizes)
-    axis_names = tuple(axis_names)
-    try:  # new signature: AbstractMesh(axis_sizes, axis_names)
-        return jax.sharding.AbstractMesh(axis_sizes, axis_names)
-    except TypeError:  # 0.4.x signature: AbstractMesh(((name, size), ...))
-        return jax.sharding.AbstractMesh(tuple(zip(axis_names, axis_sizes)))
+    """``AbstractMesh`` from (sizes, names)."""
+    return jax.sharding.AbstractMesh(tuple(int(s) for s in axis_sizes),
+                                     tuple(axis_names))
 
 
 def set_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    Newer JAX: ``jax.set_mesh``. 0.4.x: ``jax.sharding.use_mesh`` when
-    present, else the concrete Mesh's own context manager (which is what
-    pjit-era code used); AbstractMesh falls back to a no-op — shardings in
-    this repo are always passed explicitly, the ambient mesh is only a
-    convenience for ``jax.jit`` sharding propagation.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    if isinstance(mesh, jax.sharding.Mesh):
-        return mesh  # Mesh is itself a context manager in 0.4.x
-    return contextlib.nullcontext()
+    """Context manager installing ``mesh`` as the ambient mesh."""
+    return jax.set_mesh(mesh)
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_rep=False):
-    """``jax.shard_map`` (new) / ``jax.experimental.shard_map`` (0.4.x).
-
-    The 0.4.x spelling of the replication-check kwarg is ``check_rep``;
-    newer JAX renamed it ``check_vma``. Callers here always want it off —
-    the MoE/cache bodies do collective-free per-rank work.
-    """
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=check_rep)
-        except TypeError:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=check_rep)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_rep)
+def shard_map(f, *, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with the replication check off: every body here
+    does collective-free per-rank work."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` normalized to one flat dict.
+    """``compiled.cost_analysis()`` as one flat per-device dict."""
+    return dict(compiled.cost_analysis() or {})
 
-    JAX 0.4.x returns a list with one properties-dict per partition (often
-    length 1 post-SPMD); newer JAX returns the dict directly. Callers always
-    want the single per-device dict.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)
+
+def eval_jaxpr(closed_jaxpr, *args):
+    """Evaluate a ``ClosedJaxpr`` on concrete arguments."""
+    return jax.core.eval_jaxpr(closed_jaxpr.jaxpr, closed_jaxpr.consts,
+                               *args)
+
+
+def source_summary(eqn) -> str:
+    """``file:line (function)`` of the user frame that emitted ``eqn``."""
+    from jax._src import source_info_util
+    return str(source_info_util.summarize(eqn.source_info))
+
+
+def pallas_grid_mapping(eqn):
+    """The ``GridMapping`` of a ``pallas_call`` equation: ``grid``,
+    ``num_inputs``/``num_outputs``, ``num_scratch_operands``,
+    ``num_dynamic_grid_bounds`` and one ``block_mappings`` entry per
+    input then output (each with ``index_map_jaxpr``; see
+    :func:`pallas_block_sizes`)."""
+    return eqn.params["grid_mapping"]
+
+
+def pallas_block_sizes(block_mapping) -> tuple:
+    """Per-dim block extents of one Pallas ``BlockMapping`` as ints.
+
+    Entries are ``Blocked(block_size=n)`` (``Element`` and bounded
+    slices carry a ``block_size`` too) or ``Squeezed()``, a size-1 dim
+    the kernel does not see."""
+    return tuple(int(getattr(b, "block_size", 1) or 1)
+                 for b in block_mapping.block_shape)
 
 
 def _dealias_donated(args, donate_argnums):
@@ -154,7 +145,7 @@ def _dealias_donated(args, donate_argnums):
 def jit_donated(fn, donate_argnums=(), **jit_kwargs):
     """``jax.jit`` with ``donate_argnums``, absorbing donation quirks.
 
-    Two backend/version quirks are handled here so call sites stay clean:
+    Two backend quirks are handled here so call sites stay clean:
     duplicate-buffer donation (aliased zero pages in freshly initialized
     state pytrees) is de-aliased per call, and the "donated buffers were
     not usable" warning some backends emit instead of donating is

@@ -15,6 +15,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core import f32
+
 AGGS = ("last", "mean", "sum", "min", "max", "std", "count")
 
 # repro.kernels.window_agg stats-column layout:
@@ -55,16 +57,16 @@ def window_agg(values, mask, agg: str, *, use_pallas: bool = False):
         take = jnp.take_along_axis(values, jnp.maximum(idx, 0)[..., None], -1)[..., 0]
         return jnp.where(idx >= 0, take, 0.0)
     if agg == "mean":
-        return jnp.einsum("est,est->es", values, w) / jnp.maximum(n, 1)
+        return f32.einsum("est,est->es", values, w) / jnp.maximum(n, 1)
     if agg == "sum":
-        return jnp.einsum("est,est->es", values, w)
+        return f32.einsum("est,est->es", values, w)
     if agg == "min":
         return jnp.min(jnp.where(mask, values, big), -1)
     if agg == "max":
         return jnp.max(jnp.where(mask, values, -big), -1)
     if agg == "std":
-        m = jnp.einsum("est,est->es", values, w) / jnp.maximum(n, 1)
-        v = jnp.einsum("est,est->es", jnp.square(values - m[..., None]), w)
+        m = f32.einsum("est,est->es", values, w) / jnp.maximum(n, 1)
+        v = f32.einsum("est,est->es", jnp.square(values - m[..., None]), w)
         return jnp.sqrt(v / jnp.maximum(n, 1))
     if agg == "count":
         return n
@@ -78,7 +80,7 @@ def combine(values, weights):
     temperature streams is the paper's weighted-average example; a row of
     ones over feeder streams is a total-consumption sum.
     """
-    return jnp.einsum("est,fs->eft", values, weights)
+    return f32.einsum("est,fs->eft", values, weights)
 
 
 def feature_vector(values, mask, weights, *, per_tick: bool = False,
@@ -101,5 +103,5 @@ def feature_vector(values, mask, weights, *, per_tick: bool = False,
     if feature_agg != "last":
         per_stream = window_agg(values, mask, feature_agg,
                                 use_pallas=use_pallas)   # (E, S)
-        return jnp.einsum("es,fs->ef", per_stream, weights)
+        return f32.einsum("es,fs->ef", per_stream, weights)
     return combine(values, weights)[..., -1]

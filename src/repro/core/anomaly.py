@@ -15,6 +15,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import f32
+
 POLICIES = ("clip", "mean", "missing")
 
 
@@ -69,9 +71,9 @@ def update_state(state: AnomalyState, values, observed,
                  alpha: float = 0.05) -> AnomalyState:
     """Exponential Welford over clean observed ticks (batched over E, S)."""
     n = observed.sum(-1)
-    mean_w = jnp.einsum("est,est->es", values, observed.astype(jnp.float32)) \
+    mean_w = f32.einsum("est,est->es", values, observed.astype(jnp.float32)) \
         / jnp.maximum(n, 1)
-    var_w = jnp.einsum("est,est->es", jnp.square(values - mean_w[..., None]),
+    var_w = f32.einsum("est,est->es", jnp.square(values - mean_w[..., None]),
                        observed.astype(jnp.float32)) / jnp.maximum(n, 1)
     has = n > 0
     boot = state.count < 1

@@ -27,6 +27,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import f32
+
 STRATEGIES = ("locf", "linear", "ewma", "seasonal")
 
 
@@ -68,7 +70,7 @@ def _locf_scan(values, observed, init_value, init_has):
         key = jnp.where(o[..., None, :] & tril, j, -1)       # (..., T1, T1)
         li = key.max(-1)                                     # latest obs <= t
         oh = (li[..., None] == j).astype(jnp.float32)
-        cv = jnp.einsum("...j,...tj->...t", v, oh)
+        cv = f32.einsum("...j,...tj->...t", v, oh)
         return cv[..., 1:], (li >= 0)[..., 1:]
 
     def combine(a, b):
@@ -178,11 +180,11 @@ def gap_fill(values, observed, state: GapFillState, tick_ts,
     ts_b = jnp.broadcast_to(tick_ts[:, None, :], values.shape)
     last_key = jnp.where(observed, ts_b, -big)
     is_last = (last_key == last_key.max(-1, keepdims=True)) & observed
-    new_last = jnp.einsum("est,est->es", values,
+    new_last = f32.einsum("est,est->es", values,
                           is_last.astype(jnp.float32)) / \
         jnp.maximum(is_last.sum(-1), 1)
     new_last_ts = jnp.max(jnp.where(observed, ts_b, -1e30), axis=-1)
-    obs_mean = jnp.einsum("est,est->es", values, observed.astype(jnp.float32)) \
+    obs_mean = f32.einsum("est,est->es", values, observed.astype(jnp.float32)) \
         / jnp.maximum(observed.sum(-1), 1)
     sea_mean, sea_n = _seasonal_update(state, values, observed, tick_of_day)
     new_state = GapFillState(
@@ -201,9 +203,10 @@ def _seasonal_update(state, values, observed, tick_of_day):
     K = state.seasonal.shape[-1]
     oh = (jax.nn.one_hot(tick_of_day % K, K, dtype=jnp.float32)[:, None])  # (E,1,T,K)
     w = oh * observed[..., None]
-    s = jnp.einsum("est,estk->esk", values, w)
+    s = f32.einsum("est,estk->esk", values, w)
     # phrased as a dot: XLA:CPU's strided reduce of (E,S,T,K) over T is
-    # ~6x slower than the equivalent contraction (see harmonize._harmonize_dense)
+    # ~6x slower than the equivalent contraction (see harmonize._harmonize_dense);
+    # its 0/1 operands are exact in bfloat16, so default precision suffices
     n = jnp.einsum("est,estk->esk", jnp.ones_like(values), w)
     total_n = state.seasonal_n + n
     mean = jnp.where(total_n > 0,

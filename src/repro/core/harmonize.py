@@ -19,6 +19,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core import f32
 from repro.core.frame import RawWindow
 
 AGGS = ("mean", "last", "sum", "min", "max")
@@ -61,9 +62,10 @@ def _harmonize_dense(values, timestamps, idx, ok, T: int, agg: str):
     if agg in ("mean", "sum"):
         w = ((idx[..., None] == jnp.arange(T))
              & ok[..., None]).astype(jnp.float32)               # (E,S,M,T)
+        # 0/1 operands are exact in bfloat16: default precision suffices
         count = jnp.einsum("esm,esmt->est", jnp.ones_like(values), w)
         observed = count > 0
-        total = jnp.einsum("esm,esmt->est", values, w)
+        total = f32.einsum("esm,esmt->est", values, w)
         out = total if agg == "sum" else total / jnp.maximum(count, 1.0)
         return jnp.where(observed, out, 0.0), observed
 
@@ -152,7 +154,7 @@ def harmonize(raw: RawWindow, tick_ts, tick_s: float, agg: str = "mean",
     observed = count > 0
 
     v = raw.values
-    sum_v = jnp.einsum("esm,esmt->est", v, w)
+    sum_v = f32.einsum("esm,esmt->est", v, w)
     mean_v = sum_v / jnp.maximum(count, 1.0)
     big = jnp.float32(3.4e38)
     min_v = jnp.min(jnp.where(onehot, v[..., None], big), axis=2)
@@ -160,7 +162,7 @@ def harmonize(raw: RawWindow, tick_ts, tick_s: float, agg: str = "mean",
     # last = sample with max timestamp within the bucket
     ts_key = jnp.where(onehot, raw.timestamps[..., None], -big)
     last_sel = ts_key == ts_key.max(axis=2, keepdims=True)
-    last_v = jnp.einsum("esm,esmt->est", v,
+    last_v = f32.einsum("esm,esmt->est", v,
                         (last_sel & onehot).astype(jnp.float32)) / \
         jnp.maximum((last_sel & onehot).sum(axis=2), 1)
 
@@ -199,8 +201,8 @@ def harmonize_interp(raw: RawWindow, tick_ts, *, max_gap_s: float = 0.0,
     sel_hi = after & (ts[:, :, None, :] == t_hi[..., None])
     den_lo = jnp.maximum(sel_lo.sum(-1), 1)
     den_hi = jnp.maximum(sel_hi.sum(-1), 1)
-    v_lo = jnp.einsum("estm,esm->est", sel_lo.astype(jnp.float32), raw.values) / den_lo
-    v_hi = jnp.einsum("estm,esm->est", sel_hi.astype(jnp.float32), raw.values) / den_hi
+    v_lo = f32.einsum("estm,esm->est", sel_lo.astype(jnp.float32), raw.values) / den_lo
+    v_hi = f32.einsum("estm,esm->est", sel_hi.astype(jnp.float32), raw.values) / den_hi
     has_lo = t_lo > -big
     has_hi = t_hi < big
 

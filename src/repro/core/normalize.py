@@ -11,6 +11,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import f32
+
 
 class NormState(NamedTuple):
     count: jax.Array  # (E, S)
@@ -31,8 +33,8 @@ def update(state: NormState, values, observed) -> NormState:
     stats — one vectorized step per window, no per-sample loop."""
     w = observed.astype(jnp.float32)
     nb = w.sum(-1)
-    mb = jnp.einsum("est,est->es", values, w) / jnp.maximum(nb, 1)
-    m2b = jnp.einsum("est,est->es", jnp.square(values - mb[..., None]), w)
+    mb = f32.einsum("est,est->es", values, w) / jnp.maximum(nb, 1)
+    m2b = f32.einsum("est,est->es", jnp.square(values - mb[..., None]), w)
     na = state.count
     n = na + nb
     delta = mb - state.mean

@@ -42,8 +42,8 @@ Three execution modes (the measured §Perf axis on CPU, same math):
     collectives and bit-identity with the unsharded engine hold just like
     ``scan_sharded``.
 
-All mesh/shard_map spellings route through ``repro.compat`` (JAX 0.4.x ..
-0.7 support matrix in ROADMAP.md).
+All mesh/shard_map spellings route through ``repro.compat`` (JAX support
+matrix in ROADMAP.md).
 
 State is a single pytree carried tick-to-tick (gap-fill memory, anomaly
 stats, normalizer stats) — checkpointable alongside model params.
@@ -82,6 +82,7 @@ import jax.numpy as jnp
 from repro import compat
 from repro.core import aggregate as agg
 from repro.core import anomaly as an
+from repro.core import f32
 from repro.core import gapfill as gf
 from repro.core import harmonize as hz
 from repro.core import normalize as nz
@@ -234,7 +235,7 @@ def tick(cfg: PipelineConfig, state: PipelineState, raw: RawWindow,
     last_ts = ts_b.max(-1)
     has = last_ts > -big
     is_last = (ts_b == last_ts[..., None]) & raw.valid
-    last_v = jnp.einsum("esm,esm->es", raw.values, is_last.astype(jnp.float32)) \
+    last_v = f32.einsum("esm,esm->es", raw.values, is_last.astype(jnp.float32)) \
         / jnp.maximum(is_last.sum(-1), 1)
     new_state = PipelineState(
         gapfill=new_gap, anomaly=new_anom, norm=new_norm,
@@ -606,7 +607,7 @@ class PerceptaPipeline:
         last_ts = ts_b.max(-1)
         has = last_ts > -big
         is_last = (ts_b == last_ts[..., None]) & raw.valid
-        last_v = jnp.einsum("esm,esm->es", raw.values,
+        last_v = f32.einsum("esm,esm->es", raw.values,
                             is_last.astype(jnp.float32)) / \
             jnp.maximum(is_last.sum(-1), 1)
         new_state = PipelineState(

@@ -124,7 +124,7 @@ def make_abstract_mesh(mesh_shape) -> "jax.sharding.AbstractMesh":
     """Planner-only mesh from ``((name, size), ...)`` — no devices needed.
 
     Routes through ``repro.compat`` because ``AbstractMesh``'s constructor
-    signature differs between JAX 0.4.x and newer releases; every
+    signature is a version seam of JAX's API; every
     NamedSharding the planner emits is mesh-shape-only, so an abstract mesh
     is enough to unit-test resolution against a 256-chip topology.
     """

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import pallas_call
+
 DEFAULT_QBLK = 128
 DEFAULT_KBLK = 128
 NEG_INF = -1e30
@@ -70,8 +72,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
 def flash_attention_pallas(q, k, v, *, window: int = 0, softcap: float = 0.0,
                            q_blk: int = DEFAULT_QBLK,
-                           kv_blk: int = DEFAULT_KBLK,
-                           interpret: bool = True):
+                           kv_blk: int = DEFAULT_KBLK):
     """q: (B, H, S, D); k, v: (B, Hkv, S, D). Returns (B, H, S, D)."""
     B, H, S, D = q.shape
     Hkv = k.shape[1]
@@ -85,8 +86,9 @@ def flash_attention_pallas(q, k, v, *, window: int = 0, softcap: float = 0.0,
                              softcap=softcap, kv_blocks=nk, q_blk=q_blk,
                              kv_blk=kv_blk)
     grid = (B, H, nq, nk)
-    return pl.pallas_call(
+    return pallas_call(
         kern,
+        name="flash_attention",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, q_blk, D), lambda b, h, qi, ki: (b, h, qi, 0)),
@@ -104,5 +106,4 @@ def flash_attention_pallas(q, k, v, *, window: int = 0, softcap: float = 0.0,
             pltpu.VMEM((q_blk, 1), jnp.float32),
             pltpu.VMEM((q_blk, 1), jnp.float32),
         ],
-        interpret=interpret,
     )(q, k, v)

@@ -11,10 +11,10 @@ from repro.kernels.flash_attention.ref import attention_ref
 
 
 @functools.partial(jax.jit, static_argnames=("window", "softcap",
-                                             "use_pallas", "interpret",
+                                             "use_pallas",
                                              "q_blk", "kv_blk"))
 def flash_attention(q, k, v, *, window: int = 0, softcap: float = 0.0,
-                    use_pallas: bool = True, interpret: bool = True,
+                    use_pallas: bool = True,
                     q_blk: int = 128, kv_blk: int = 128):
     """Model layout in/out: q (B, S, H, D); k, v (B, S, Hkv, D)."""
     qt = q.transpose(0, 2, 1, 3)
@@ -22,7 +22,7 @@ def flash_attention(q, k, v, *, window: int = 0, softcap: float = 0.0,
     vt = v.transpose(0, 2, 1, 3)
     if use_pallas:
         out = flash_attention_pallas(qt, kt, vt, window=window,
-                                     softcap=softcap, interpret=interpret,
+                                     softcap=softcap,
                                      q_blk=q_blk, kv_blk=kv_blk)
     else:
         out = attention_ref(qt, kt, vt, window=window, softcap=softcap)

@@ -6,32 +6,36 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.harmonize.kernel import ROWS_BLK, harmonize_pallas
+from repro.kernels.harmonize.kernel import LANES, harmonize_pallas
 from repro.kernels.harmonize.ref import harmonize_ref
 
 
 @functools.partial(jax.jit, static_argnames=("tick_s", "n_ticks",
-                                             "use_pallas", "interpret"))
+                                             "use_pallas"))
 def harmonize(values, timestamps, valid, window_start, *, tick_s: float,
-              n_ticks: int, use_pallas: bool = True, interpret: bool = True):
+              n_ticks: int, use_pallas: bool = True):
     """Batched entry: (E, S, M) raw samples -> (E, S, T) tick means.
 
     window_start: (E,). Returns (values (E,S,T), observed (E,S,T)).
     """
     E, S, M = values.shape
-    v = values.reshape(E * S, M).astype(jnp.float32)
-    ts = timestamps.reshape(E * S, M).astype(jnp.float32)
-    ok = valid.reshape(E * S, M).astype(jnp.float32)
-    t0 = jnp.broadcast_to(window_start[:, None], (E, S)).reshape(E * S, 1)
+    R = E * S
+    t0 = jnp.broadcast_to(window_start[:, None], (E, S)).reshape(R, 1)
     if not use_pallas:
-        out, obs = harmonize_ref(v, ts, ok > 0, t0[:, 0], tick_s, n_ticks)
-    else:
-        pad = (-v.shape[0]) % ROWS_BLK
-        if pad:
-            zp = lambda x: jnp.pad(x, ((0, pad), (0, 0)))
-            v, ts, ok, t0 = zp(v), zp(ts), zp(ok), zp(t0)
-        out, obs = harmonize_pallas(v, ts, ok, t0, tick_s=tick_s,
-                                    n_ticks=n_ticks, interpret=interpret)
-        if pad:
-            out, obs = out[:E * S], obs[:E * S]
-    return out.reshape(E, S, n_ticks), obs.reshape(E, S, n_ticks)
+        out, obs = harmonize_ref(
+            values.reshape(R, M).astype(jnp.float32),
+            timestamps.reshape(R, M).astype(jnp.float32),
+            valid.reshape(R, M).astype(jnp.float32) > 0, t0[:, 0], tick_s,
+            n_ticks)
+        return out.reshape(E, S, n_ticks), obs.reshape(E, S, n_ticks)
+    pad = (-R) % LANES
+
+    def lanes(x, n):   # (R, n) -> (n, R + pad): rows onto lanes
+        x = x.reshape(R, n).astype(jnp.float32).T
+        return jnp.pad(x, ((0, 0), (0, pad))) if pad else x
+
+    out, obs = harmonize_pallas(lanes(values, M), lanes(timestamps, M),
+                                lanes(valid, M), lanes(t0, 1),
+                                tick_s=tick_s, n_ticks=n_ticks)
+    unlanes = lambda x: x[:, :R].T.reshape(E, S, n_ticks)
+    return unlanes(out), unlanes(obs)

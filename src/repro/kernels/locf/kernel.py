@@ -1,8 +1,15 @@
 """Pallas TPU kernel: LOCF gap filling in one VMEM pass.
 
 The XLA associative_scan materializes O(log T) full-size intermediates in
-HBM; the kernel walks T once per (rows, T) tile with the carry in VREGs —
-the gap-fill stage becomes a single streaming read+write.
+HBM; the kernel walks T once per tile with the carry in vregs, one
+streaming read+write. Its wrapper (``ops.locf``) transposes the (E, S, T)
+inputs to (T, E*S) before the kernel and its outputs back after it, so
+the stage as a whole makes about two more passes over HBM.
+
+Layout: rows (E*S) on the 128 lanes, ticks on sublanes. Each grid step
+owns a (T, LANES) tile and reads/writes one tick row per loop step with a
+dynamic sublane slice, which Mosaic lowers; a dynamic LANE index (one tick
+column of an (R, T) tile) it does not.
 """
 from __future__ import annotations
 
@@ -10,51 +17,41 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-ROWS_BLK = 8
+from repro.kernels import pallas_call
+
+LANES = 128
 
 
 def _kernel(values_ref, obs_ref, init_v_ref, init_h_ref, out_ref, has_ref):
-    R, T = values_ref.shape
-    v = values_ref[...].astype(jnp.float32)
-    o = obs_ref[...] > 0
-    carry_v = init_v_ref[...].astype(jnp.float32)   # (R, 1)
-    carry_h = init_h_ref[...] > 0
+    T = values_ref.shape[0]
 
     def body(t, carry):
         cv, ch = carry
-        vt = v[:, t][:, None]
-        ot = o[:, t][:, None]
-        cv = jnp.where(ot, vt, cv)
-        ch = ch | ot
-        out_ref[:, t] = cv[:, 0]
-        has_ref[:, t] = ch[:, 0].astype(jnp.float32)
+        ot = obs_ref[pl.ds(t, 1), :] > 0
+        cv = jnp.where(ot, values_ref[pl.ds(t, 1), :], cv)
+        ch = jnp.where(ot, 1.0, ch)
+        out_ref[pl.ds(t, 1), :] = cv
+        has_ref[pl.ds(t, 1), :] = ch
         return cv, ch
 
-    jax.lax.fori_loop(0, T, body, (carry_v, carry_h))
+    jax.lax.fori_loop(0, T, body, (init_v_ref[...], init_h_ref[...]))
 
 
-def locf_pallas(values, observed, init_value, init_has, *,
-                interpret: bool = True):
-    """values/observed: (R, T) f32; init_value/init_has: (R, 1) f32."""
-    R, T = values.shape
-    assert R % ROWS_BLK == 0
-    out, has = pl.pallas_call(
+def locf_pallas(values, observed, init_value, init_has):
+    """values/observed: (T, R) f32; init_value/init_has: (1, R) f32.
+
+    R % LANES == 0 (pad upstream). Returns (filled (T, R), has (T, R)).
+    """
+    T, R = values.shape
+    assert R % LANES == 0, R
+    tile = pl.BlockSpec((T, LANES), lambda i: (0, i))
+    carry = pl.BlockSpec((1, LANES), lambda i: (0, i))
+    out, has = pallas_call(
         _kernel,
-        grid=(R // ROWS_BLK,),
-        in_specs=[
-            pl.BlockSpec((ROWS_BLK, T), lambda i: (i, 0)),
-            pl.BlockSpec((ROWS_BLK, T), lambda i: (i, 0)),
-            pl.BlockSpec((ROWS_BLK, 1), lambda i: (i, 0)),
-            pl.BlockSpec((ROWS_BLK, 1), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((ROWS_BLK, T), lambda i: (i, 0)),
-            pl.BlockSpec((ROWS_BLK, T), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((R, T), jnp.float32),
-            jax.ShapeDtypeStruct((R, T), jnp.float32),
-        ],
-        interpret=interpret,
+        name="locf",
+        grid=(R // LANES,),
+        in_specs=[tile, tile, carry, carry],
+        out_specs=[tile, tile],
+        out_shape=[jax.ShapeDtypeStruct((T, R), jnp.float32)] * 2,
     )(values, observed, init_value, init_has)
     return out, has > 0

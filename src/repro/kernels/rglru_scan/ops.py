@@ -18,8 +18,8 @@ from repro.kernels.rglru_scan.kernel import LANES, rglru_scan_pallas
 from repro.kernels.rglru_scan.ref import rglru_scan_ref
 
 
-@functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
-def rglru_scan(a, b, h0, *, use_pallas: bool = True, interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("use_pallas",))
+def rglru_scan(a, b, h0, *, use_pallas: bool = True):
     """h_t = a_t h_{t-1} + b_t over axis 1. a, b: (B, T, W); h0: (B, W)."""
     if not use_pallas:
         return rglru_scan_ref(a, b, h0)
@@ -31,8 +31,8 @@ def rglru_scan(a, b, h0, *, use_pallas: bool = True, interpret: bool = True):
         h0 = jnp.pad(h0, ((0, 0), (0, pad)))
     out, hlast = rglru_scan_pallas(a.astype(jnp.float32),
                                    b.astype(jnp.float32),
-                                   h0.astype(jnp.float32),
-                                   interpret=interpret)
+                                   h0.astype(jnp.float32).reshape(B, 1, -1))
+    hlast = hlast.reshape(B, -1)
     if pad:
         out, hlast = out[..., :W], hlast[..., :W]
     return out, hlast

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import pallas_call
 from repro.kernels.window_agg.ref import N_STATS
 
 ROWS_BLK = 8
@@ -57,14 +58,15 @@ def _kernel(values_ref, mask_ref, mean_ref, var_ref, stats_ref, spikes_ref,
 
 
 def window_agg_pallas(values, mask, state_mean, state_var, *,
-                      k_sigma: float = 6.0, interpret: bool = True):
+                      k_sigma: float = 6.0):
     """values/mask: (R, T); state_mean/var: (R, 1) f32 (lane-padded)."""
     R, T = values.shape
     assert R % ROWS_BLK == 0, R
     grid = (R // ROWS_BLK,)
     kern = functools.partial(_kernel, k_sigma=k_sigma)
-    stats, spikes = pl.pallas_call(
+    stats, spikes = pallas_call(
         kern,
+        name="window_agg",
         grid=grid,
         in_specs=[
             pl.BlockSpec((ROWS_BLK, T), lambda i: (i, 0)),
@@ -80,6 +82,5 @@ def window_agg_pallas(values, mask, state_mean, state_var, *,
             jax.ShapeDtypeStruct((R, LANES), jnp.float32),
             jax.ShapeDtypeStruct((R, T), jnp.float32),
         ],
-        interpret=interpret,
     )(values, mask, state_mean, state_var)
     return stats[:, :N_STATS], spikes > 0

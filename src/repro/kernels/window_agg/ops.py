@@ -18,10 +18,9 @@ def _pad_rows(x, mult):
     return x, pad
 
 
-@functools.partial(jax.jit, static_argnames=("k_sigma", "use_pallas",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("k_sigma", "use_pallas"))
 def window_agg(values, mask, state_mean, state_var, *, k_sigma: float = 6.0,
-               use_pallas: bool = True, interpret: bool = True):
+               use_pallas: bool = True):
     """Batched entry: values/mask (E, S, T); state (E, S).
 
     Returns (stats (E, S, N_STATS), spikes (E, S, T)).
@@ -38,8 +37,7 @@ def window_agg(values, mask, state_mean, state_var, *, k_sigma: float = 6.0,
         m, _ = _pad_rows(m, ROWS_BLK)
         mu, _ = _pad_rows(mu, ROWS_BLK)
         var2, _ = _pad_rows(var, ROWS_BLK)
-        stats, spikes = window_agg_pallas(v, m, mu, var2, k_sigma=k_sigma,
-                                          interpret=interpret)
+        stats, spikes = window_agg_pallas(v, m, mu, var2, k_sigma=k_sigma)
         if pad:
             stats, spikes = stats[:E * S], spikes[:E * S]
     return stats.reshape(E, S, -1), spikes.reshape(E, S, T)

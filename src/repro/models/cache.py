@@ -116,8 +116,7 @@ def write_token(buf, new, lengths, window: int = 0, shard=None):
         local, mesh=mesh,
         in_specs=(P(bspec, "model", None, None), P(bspec, None, None, None),
                   P(bspec)),
-        out_specs=P(bspec, "model", None, None),
-        check_rep=False)
+        out_specs=P(bspec, "model", None, None))
     return fn(buf, new, idx)
 
 

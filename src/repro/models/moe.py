@@ -124,8 +124,7 @@ def moe_apply_sharded(p, x, cfg, mesh, dp_axes):
         in_specs=(P(dp_spec, None, None), P(None, None),
                   P("model", None, None), P("model", None, None),
                   P("model", None, None)),
-        out_specs=(P(dp_spec, None, None), P()),
-        check_rep=False)
+        out_specs=(P(dp_spec, None, None), P()))
     return fn(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
 
 

@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.analysis.certify import certify_policy
-from repro.runtime.predictor import ModelAdapter, linear_policy
+from repro.runtime.predictor import ModelAdapter, host_params, linear_policy
 
 
 def _rowdot(x, w):
@@ -71,14 +71,17 @@ def mlp_builder(n_features: int, n_actions: int, n_envs: int = None,
                 low=-1.0, high=1.0) -> ModelAdapter:
     """Two-layer gated MLP (SwiGLU), stateless and row-wise."""
     del n_envs
-    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
-    params = {
-        "w1": jax.random.normal(k1, (n_features, hidden))
-        / jnp.sqrt(n_features),
-        "w3": jax.random.normal(k2, (n_features, hidden))
-        / jnp.sqrt(n_features),
-        "w2": jax.random.normal(k3, (hidden, n_actions)) / jnp.sqrt(hidden),
-    }
+    def init():
+        k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
+        return {
+            "w1": jax.random.normal(k1, (n_features, hidden))
+            / jnp.sqrt(n_features),
+            "w3": jax.random.normal(k2, (n_features, hidden))
+            / jnp.sqrt(n_features),
+            "w2": jax.random.normal(k3, (hidden, n_actions))
+            / jnp.sqrt(hidden),
+        }
+    params = host_params(init)
 
     def apply(params, feats):
         h = _rowdot(feats, params["w1"])
@@ -104,19 +107,21 @@ def rglru_builder(n_features: int, n_actions: int, n_envs: int = None,
     from repro.kernels.rglru_scan import ops
 
     del n_envs  # carry is built by init_carry at the system's env count
-    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
-    params = {
-        "w_in": jax.random.normal(ks[0], (n_features, hidden))
-        / jnp.sqrt(n_features),
-        "w_a": jax.random.normal(ks[1], (hidden,)) * 0.1,
-        "b_a": jnp.zeros((hidden,)),
-        "w_i": jax.random.normal(ks[2], (hidden,)) * 0.1,
-        "b_i": jnp.zeros((hidden,)),
-        # softplus(lam) in (0, 1)-ish: forget rates spread across the units
-        "lam": jnp.linspace(-2.0, 1.0, hidden),
-        "w_out": jax.random.normal(ks[3], (hidden, n_actions))
-        / jnp.sqrt(hidden),
-    }
+    def init():
+        ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+        return {
+            "w_in": jax.random.normal(ks[0], (n_features, hidden))
+            / jnp.sqrt(n_features),
+            "w_a": jax.random.normal(ks[1], (hidden,)) * 0.1,
+            "b_a": jnp.zeros((hidden,)),
+            "w_i": jax.random.normal(ks[2], (hidden,)) * 0.1,
+            "b_i": jnp.zeros((hidden,)),
+            # softplus(lam) in (0, 1)-ish: forget rates spread over the units
+            "lam": jnp.linspace(-2.0, 1.0, hidden),
+            "w_out": jax.random.normal(ks[3], (hidden, n_actions))
+            / jnp.sqrt(hidden),
+        }
+    params = host_params(init)
 
     def apply_carry(params, feats, carry):
         h = carry["h"]                                   # (E, H)
@@ -150,22 +155,25 @@ def rwkv6_builder(n_features: int, n_actions: int, n_envs: int = None,
     Carry: ``{"shift": (E, F), "wkv": (E, hidden, hidden)}``.
     """
     del n_envs
-    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
     D = hidden
-    params = {
-        "mu": jax.random.uniform(ks[0], (4, n_features)),   # r/k/v/w mixes
-        "w_r": jax.random.normal(ks[1], (n_features, D))
-        / jnp.sqrt(n_features),
-        "w_k": jax.random.normal(ks[2], (n_features, D))
-        / jnp.sqrt(n_features),
-        "w_v": jax.random.normal(ks[3], (n_features, D))
-        / jnp.sqrt(n_features),
-        "w_decay": jax.random.normal(ks[4], (n_features, D))
-        / jnp.sqrt(n_features),
-        "decay_base": jnp.zeros((D,)),
-        "bonus": jnp.zeros((D,)),
-        "w_o": jax.random.normal(ks[5], (D, n_actions)) / jnp.sqrt(D),
-    }
+
+    def init():
+        ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+        return {
+            "mu": jax.random.uniform(ks[0], (4, n_features)),  # r/k/v/w mixes
+            "w_r": jax.random.normal(ks[1], (n_features, D))
+            / jnp.sqrt(n_features),
+            "w_k": jax.random.normal(ks[2], (n_features, D))
+            / jnp.sqrt(n_features),
+            "w_v": jax.random.normal(ks[3], (n_features, D))
+            / jnp.sqrt(n_features),
+            "w_decay": jax.random.normal(ks[4], (n_features, D))
+            / jnp.sqrt(n_features),
+            "decay_base": jnp.zeros((D,)),
+            "bonus": jnp.zeros((D,)),
+            "w_o": jax.random.normal(ks[5], (D, n_actions)) / jnp.sqrt(D),
+        }
+    params = host_params(init)
 
     def apply_carry(params, feats, carry):
         shift, S = carry["shift"], carry["wkv"]          # (E,F), (E,D,D)

@@ -237,6 +237,19 @@ def policy_call2(model):
     return apply2, params, None
 
 
+def host_params(init: Callable[[], dict]) -> dict:
+    """Run the weight initializer ``init`` on the host CPU backend and
+    return its pytree as numpy arrays.
+
+    ``jax.random.normal``'s inverse-erf transform is implemented per
+    backend, and one key gave MLP weights up to 1.1e-5 apart on a TPU v5e
+    and on the CPU. Drawn on the host, a seed is the same model on every
+    backend.
+    """
+    with jax.default_device(jax.devices("cpu")[0]):
+        return jax.tree.map(np.asarray, init())
+
+
 def linear_policy(n_features: int, n_actions: int, seed: int = 0,
                   low=-1.0, high=1.0) -> ModelAdapter:
     """A small deterministic policy standing in for the deployed RL model.
@@ -251,9 +264,10 @@ def linear_policy(n_features: int, n_actions: int, seed: int = 0,
     bit-identity guarantee rests on (a custom model must preserve it too
     to compose with ``mode="scan_fused_decide_sharded"``).
     """
-    k = jax.random.PRNGKey(seed)
-    W = jax.random.normal(k, (n_features, n_actions)) / jnp.sqrt(n_features)
-    params = {"w": W}
+    params = host_params(lambda: {
+        "w": jax.random.normal(jax.random.PRNGKey(seed),
+                               (n_features, n_actions))
+        / jnp.sqrt(n_features)})
 
     def apply(params, feats):
         logits = (feats[..., :, None] * params["w"][None, :, :]).sum(-2)

@@ -21,8 +21,9 @@ deterministic batch-epoch handoff protocol:
   * assembled batches travel back on a depth-1 buffer (the "double" in
     double-buffered: one batch on device, at most one staged ahead), which
     also bounds host memory when the device falls behind; this depth is
-    what sizes the system's rotating staging-buffer pool (at most three
-    batches are ever alive: assembling, staged, in flight — see
+    what sizes the system's rotating staging-buffer pool (at most four
+    batches are ever alive: assembling, staged, taken, and the previous
+    one in flight until it is consumed — see
     ``PerceptaSystem._STAGE_DEPTH``), and ``ingest_workers`` composes
     cleanly because the pump thread remains the sole pumper/drainer and
     merely fans the per-env assembly work out to its worker pool;

@@ -554,12 +554,14 @@ class PerceptaSystem:
 
     # --- scan-fused operation --------------------------------------------------
     # Staging buffers alive at once in the deepest pipeline (async modes):
-    # one being assembled by the pump, one staged in the depth-1 ready
-    # buffer, one consumed/in flight on device. ``jnp.asarray`` may
-    # zero-copy an aligned host buffer on CPU, so a buffer is only reused
-    # once its batch is provably consumed — with depth 3 the epoch reusing
-    # buffer b%3 starts only after batch b-3's results were consumed.
-    _STAGE_DEPTH = 3
+    # when the Manager takes batch j from the depth-1 ready buffer, the
+    # pump stages j+1 and starts assembling j+2 while batch j-1 — consumed
+    # only after that take — may still be executing: four batches.
+    # ``jnp.asarray`` may alias a host buffer (and a host-to-device copy
+    # reads it asynchronously), so a buffer is only reused once its batch
+    # is provably consumed — with depth 4 the epoch reusing buffer b%4
+    # starts only after batch b-4's results were consumed.
+    _STAGE_DEPTH = 4
 
     def _staging_buffers(self, K: int, E: int):
         """Rotating preallocated (K, E, S, M) staging triple, zeroed.

@@ -53,6 +53,7 @@ import jax.numpy as jnp
 
 from repro import compat
 from repro.configs.base import TrainConfig
+from repro.core import f32
 from repro.core import replay as rp
 from repro.train import optimizer as opt
 from repro.train.checkpoint import Checkpointer
@@ -67,7 +68,7 @@ def critic_init(n_features: int, n_actions: int) -> dict:
 
 def critic_apply(critic, obs, actions):
     x = jnp.concatenate([obs, actions], axis=-1)
-    return x @ critic["qw"] + critic["qb"]
+    return f32.einsum("...i,i->...", x, critic["qw"]) + critic["qb"]
 
 
 def td_loss(apply_fn, params, critic, batch, pi_coef: float = 0.1):

@@ -160,6 +160,56 @@ def test_collective_rejected_through_shard_map():
     assert "collective" in [x.rule for x in v]
 
 
+def test_compat_pallas_seams():
+    """The Pallas internals the checker reads, through ``repro.compat`` on
+    the installed JAX: a pallas_call's grid mapping, its per-dim block
+    sizes (``Blocked`` and ``Squeezed`` entries) and index-map
+    evaluation."""
+    from jax.experimental import pallas as pl
+
+    def kernel(x_ref, o_ref):
+        o_ref[...] = x_ref[0] * 2.0
+
+    def fn(x):
+        return pl.pallas_call(
+            kernel, grid=(4,),
+            in_specs=[pl.BlockSpec((1, 128), lambda i: (i, 0))],
+            out_specs=pl.BlockSpec((None, 128), lambda i: (3 - i, 0)),
+            out_shape=jax.ShapeDtypeStruct((4, 128), jnp.float32),
+            interpret=True)(x)
+
+    eqn, = [e for e in jax.make_jaxpr(fn)(jnp.ones((4, 128))).jaxpr.eqns
+            if e.primitive.name == "pallas_call"]
+    gm = compat.pallas_grid_mapping(eqn)
+    assert tuple(gm.grid) == (4,)
+    assert (gm.num_inputs, gm.num_outputs) == (1, 1)
+    bm_in, bm_out = gm.block_mappings
+    assert compat.pallas_block_sizes(bm_in) == (1, 128)
+    assert compat.pallas_block_sizes(bm_out) == (1, 128)
+    routes = [tuple(int(r) for r in
+                    compat.eval_jaxpr(bm_out.index_map_jaxpr, np.int32(i)))
+              for i in range(4)]
+    assert routes == [(3, 0), (2, 0), (1, 0), (0, 0)]
+    assert "test_analysis.py" in compat.source_summary(eqn)
+
+
+def test_compile_cache_location(monkeypatch):
+    """Entry points keep JAX's compile cache where
+    ``JAX_COMPILATION_CACHE_DIR`` says, else at ``<checkout>/.jax_cache``."""
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        compat.enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir is None  # JAX's choice
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        compat.enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == \
+            os.path.join(REPO, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
 def test_reward_shape_rule():
     with pytest.raises(ContractViolation) as ei:
         check_reward_fn(lambda f, a, p: f[:1, 0], E, F, A)

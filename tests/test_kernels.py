@@ -1,5 +1,5 @@
 """Per-Pallas-kernel validation: shape/dtype sweeps vs the pure-jnp oracles
-(interpret=True executes the kernel bodies on CPU)."""
+(lowered for the CPU, the kernel bodies run in the Pallas interpreter)."""
 import jax.numpy as jnp
 import numpy as np
 import pytest

@@ -85,6 +85,17 @@ def test_registry_policy_certifies_with_certificate_attached(name):
         assert "'wkv'" in cert.carry_treedef
 
 
+@pytest.mark.parametrize("name", sorted(POLICIES))
+def test_registry_weights_drawn_on_host(name):
+    # jax.random.normal differs per backend in its last digits: the
+    # weights are host arrays, drawn on the CPU, so a seed is one model
+    a = build_policy(name, F, A, E).params
+    b = build_policy(name, F, A, E).params
+    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+        assert type(x) is np.ndarray and x.dtype == np.float32
+        np.testing.assert_array_equal(x, y)
+
+
 def test_certificate_cache_skips_retracing():
     certify_mod.clear_cache()
     a = build_policy("mlp", F, A, E)

@@ -68,6 +68,37 @@ def test_scan_async_matches_scan_system(async_mode):
     sys_a.stop()
 
 
+@pytest.mark.parametrize("mode", ["scan_async", "scan_fused_decide_async"])
+def test_async_staging_buffer_reused_only_after_consume(mode):
+    """A staging buffer may be refilled only once the batch that last used
+    it was consumed: ``jnp.asarray`` can alias host memory, so the device
+    may still be reading it until then. A slow consumer lets the pump run
+    as far ahead as the depth-1 ready buffer allows."""
+    import time
+
+    s = _system(mode)
+    taken, consumed, early = [], [0], []
+    stage, consume = s._staging_buffers, s._consume_batch
+
+    def staging(K, E):
+        bufs = stage(K, E)
+        early.extend((len(taken), m) for m, b in enumerate(taken)
+                     if b is bufs[0] and m >= consumed[0])
+        taken.append(bufs[0])
+        return bufs
+
+    def slow_consume(pending):
+        time.sleep(0.2)
+        out = consume(pending)
+        consumed[0] += 1
+        return out
+
+    s._staging_buffers, s._consume_batch = staging, slow_consume
+    s.run_windows(8 * s.scan_k)
+    s.stop()
+    assert len(taken) == 8 and early == []
+
+
 def test_scan_async_chained_calls_resume_epochs():
     """A second run_windows call reuses the pump thread and stays aligned."""
     a = _system("scan")
