@@ -17,6 +17,8 @@ changes one file:
     donation quirks below.
   * ``enable_compile_cache`` — the persistent compilation cache every
     entry point shares.
+  * ``trace_annotation`` / ``trace_enabled`` — the profiler's host span
+    and whether a profiler session records (``repro.runtime.spans``).
 
 Donation quirk: some backends warn ("Some donated buffers were not
 usable") instead of donating. ``jit_donated`` applies ``donate_argnums``
@@ -49,6 +51,19 @@ def enable_compile_cache() -> None:
     if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         return
     jax.config.update("jax_compilation_cache_dir", str(_CACHE_DIR))
+
+
+def trace_annotation(name: str, **metadata):
+    """``jax.profiler.TraceAnnotation``: a named event, with ``metadata`` as
+    its stats, on the trace's host plane while a profiler session records;
+    about a microsecond to enter and leave while none does. Its
+    ``set_metadata(**kw)`` adds stats before the exit."""
+    return jax.profiler.TraceAnnotation(name, **metadata)
+
+
+def trace_enabled() -> bool:
+    """Whether a profiler session is recording host spans."""
+    return jax.profiler.TraceAnnotation.is_enabled()
 
 
 def make_mesh(devices, axis_names):

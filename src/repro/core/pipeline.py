@@ -402,6 +402,17 @@ def run_many_decide(cfg: PipelineConfig, decide, state: PipelineState,
     return final_state, final_dcarry, outs
 
 
+def _decide_program(cfg: PipelineConfig, decide):
+    """``run_many_decide`` bound to ``cfg`` and ``decide``, under its own
+    name: jit names the compiled program after ``__name__``
+    (``jit_run_many_decide`` in a profiler trace), where a bare
+    ``functools.partial`` has none and reaches the trace as
+    ``jit__unknown``."""
+    fn = functools.partial(run_many_decide, cfg, decide)
+    fn.__name__ = "run_many_decide"
+    return fn
+
+
 def make_run_many_decide_sharded(cfg: PipelineConfig, decide, dstate,
                                  mesh=None):
     """Env-sharded fused decision engine: :func:`run_many_decide` under
@@ -431,7 +442,7 @@ def make_run_many_decide_sharded(cfg: PipelineConfig, decide, dstate,
 
     if mesh is None:
         mesh = shard_lib.env_mesh(cfg.n_envs)
-    fn = functools.partial(run_many_decide, cfg, decide)
+    fn = _decide_program(cfg, decide)
     E, S, M = cfg.n_envs, cfg.n_streams, cfg.max_samples
     state_s = jax.eval_shape(lambda: init_state(cfg))
     dstate_s = jax.tree.map(
@@ -544,7 +555,7 @@ class PerceptaPipeline:
                 scan_fn, self.mesh = make_run_many_decide_sharded(
                     cfg, decide, decide_state, mesh)
             else:
-                scan_fn = functools.partial(run_many_decide, cfg, decide)
+                scan_fn = _decide_program(cfg, decide)
                 self.mesh = None
         elif mode == "scan_sharded":
             scan_fn, self.mesh = make_run_many_sharded(cfg, mesh,

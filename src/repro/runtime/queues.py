@@ -20,6 +20,7 @@ overflow.
 from __future__ import annotations
 
 import threading
+import time
 from collections import deque
 from typing import Dict, Union
 
@@ -43,7 +44,11 @@ def _head(batch: RecordBatch, n: int) -> RecordBatch:
 
 
 class EnvQueue:
-    """Thread-safe bounded queue; ``maxsize`` counts records."""
+    """Thread-safe bounded queue; ``maxsize`` counts records.
+
+    ``drained_since`` is the ``time.perf_counter()`` at which the oldest
+    item the last :meth:`drain` returned was put (None when it returned
+    nothing): the drain time minus it is how long that item waited."""
 
     def __init__(self, env_id: str, maxsize: int = 100_000):
         self.env_id = env_id
@@ -51,12 +56,16 @@ class EnvQueue:
         self._items: deque = deque()
         self._records = 0              # records currently buffered
         self._lock = threading.Lock()
+        self._first_put = 0.0          # last put that found the queue empty
+        self.drained_since = None
         self.stats = {"enqueued": 0, "dropped": 0, "dequeued": 0}
 
     def put(self, item: Item) -> bool:
         """Enqueue; returns False when any record was dropped (QoS 0)."""
         n = _n(item)
         with self._lock:
+            if not self._items:
+                self._first_put = time.perf_counter()
             free = self.maxsize - self._records
             if n <= free:
                 self._items.append(item)
@@ -78,6 +87,7 @@ class EnvQueue:
     def drain(self, max_items: int = 1_000_000):
         out = []
         with self._lock:
+            self.drained_since = self._first_put if self._items else None
             while self._items and len(out) < max_items:
                 it = self._items.popleft()
                 self._records -= _n(it)

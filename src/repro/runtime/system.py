@@ -149,6 +149,7 @@ import numpy as np
 
 from repro.core import PerceptaPipeline, PipelineConfig
 from repro.core.frame import make_raw_window
+from repro.runtime import spans
 from repro.runtime.accumulator import Accumulator
 from repro.runtime.forwarder import ForwarderHub
 from repro.runtime.predictor import Predictor
@@ -175,6 +176,25 @@ _ASYNC_MODES = ("scan_async", "scan_async_sharded",
                 "scan_fused_decide_async", "scan_fused_decide_async_sharded")
 # pipeline modes whose dispatch runs under shard_map on the env mesh
 _SHARDED_PIPE_MODES = ("scan_sharded", "scan_fused_decide_sharded")
+
+
+def _window_counts(recs, starts: np.ndarray) -> np.ndarray:
+    """Drained records per window of a batch (window starts ``starts``):
+    each record counts in the window whose bounds hold its timestamp,
+    clipped to the batch, so the counts sum to the drain total."""
+    K = len(starts)
+    c = np.zeros(K, np.int64)
+    scalar_ts = []            # one vectorized pass per drain, not per item
+    for r in recs:
+        if isinstance(r, RecordBatch):
+            j = np.searchsorted(starts, r.timestamps, side="right") - 1
+            c += np.bincount(np.clip(j, 0, K - 1), minlength=K)
+        else:
+            scalar_ts.append(r.timestamp)
+    if scalar_ts:
+        j = np.searchsorted(starts, np.asarray(scalar_ts), side="right") - 1
+        c += np.bincount(np.clip(j, 0, K - 1), minlength=K)
+    return c
 
 
 @dataclass
@@ -426,8 +446,6 @@ class PerceptaSystem:
         self.accumulators: Dict[str, Accumulator] = {}
         for env in env_ids:
             self._register_env(env)
-        self.metrics: Dict[str, list] = {"tick_latency_s": [],
-                                         "ingest_records": []}
 
     def _register_env(self, env_id: str) -> None:
         """Wire one env into every source Receiver and give it its own
@@ -540,8 +558,6 @@ class PerceptaSystem:
                                extra={"policy_version": ver})
 
         self.window_index += 1
-        self.metrics["tick_latency_s"].append(latency)
-        self.metrics["ingest_records"].append(n_new)
         return {
             "window": self.window_index - 1,
             "records": n_new,
@@ -586,31 +602,34 @@ class PerceptaSystem:
         return pool["bufs"][i]
 
     def _assemble_env(self, slot: int, env: str, bounds, starts,
-                      values, ts, valid) -> np.ndarray:
+                      values, ts, valid, tally: list = None) -> np.ndarray:
         """Drain, count, ingest and close ONE env into its staging rows.
 
         The unit of work ``ingest_workers`` partitions: everything touched
         here — the env's queue, its Accumulator, column ``slot`` of the
         staging buffers — belongs to exactly one env, so concurrent calls
-        for different envs share nothing."""
-        K = len(bounds)
-        recs = self.broker.queue_for(env).drain()
-        c = np.zeros(K, np.int64)
-        scalar_ts = []            # one vectorized pass per drain, not per item
-        for r in recs:
-            if isinstance(r, RecordBatch):
-                j = np.searchsorted(starts, r.timestamps, side="right") - 1
-                c += np.bincount(np.clip(j, 0, K - 1), minlength=K)
-            else:
-                scalar_ts.append(r.timestamp)
-        if scalar_ts:
-            j = np.searchsorted(starts, np.asarray(scalar_ts),
-                                side="right") - 1
-            c += np.bincount(np.clip(j, 0, K - 1), minlength=K)
+        for different envs share nothing. Given a ``tally`` (a traced
+        batch), the steps are clocked: drain, count + ingest and close
+        seconds add to ``tally[0:3]``, the longest queue wait is kept in
+        ``tally[3]``."""
+        timed = tally is not None
+        q = self.broker.queue_for(env)
+        t0 = time.perf_counter() if timed else 0.0
+        recs = q.drain()
+        t1 = time.perf_counter() if timed else 0.0
+        c = _window_counts(recs, starts)
         acc = self.accumulators[env]
         acc.ingest(recs)
+        t2 = time.perf_counter() if timed else 0.0
         acc.close_windows(bounds, rebase=True,
                           out=(values[:, slot], ts[:, slot], valid[:, slot]))
+        if timed:
+            t3 = time.perf_counter()
+            tally[0] += t1 - t0
+            tally[1] += t2 - t1
+            tally[2] += t3 - t2
+            if q.drained_since is not None:
+                tally[3] = max(tally[3], t1 - q.drained_since)
         return c
 
     def assemble_windows(self, bounds) -> tuple:
@@ -635,41 +654,63 @@ class PerceptaSystem:
         env-isolated, and the per-window counts are summed with integer
         adds, so the result is bit-identical to the serial loop.
         """
-        E = self.cfg.n_envs
-        K = len(bounds)
-        starts = np.asarray([b[0] for b in bounds], np.float64)
-        live = self._live_slots()
-        values, ts, valid = self._staging_buffers(K, E)
-        counts_arr = np.zeros(K, np.int64)
-        if self._ingest_pool is not None and len(live) > 1:
+        with spans.span("percepta.assemble") as sp:
+            E = self.cfg.n_envs
+            K = len(bounds)
+            starts = np.asarray([b[0] for b in bounds], np.float64)
+            live = self._live_slots()
+            values, ts, valid = self._staging_buffers(K, E)
+            counts_arr = np.zeros(K, np.int64)
+            # one tally per shard while a profiler records (see
+            # _assemble_env); None leaves the steps unclocked
+            tallies = [] if spans.tracing() else None
+
             def run_shard(shard):
+                tally = None
+                if tallies is not None:
+                    tally = [0.0, 0.0, 0.0, 0.0]
+                    tallies.append(tally)
                 return [self._assemble_env(i, env, bounds, starts,
-                                           values, ts, valid)
+                                           values, ts, valid, tally)
                         for i, env in shard]
-            shards = [live[w::self.ingest_workers]
-                      for w in range(self.ingest_workers)]
-            futs = [self._ingest_pool.submit(run_shard, sh)
-                    for sh in shards if sh]
-            for f in futs:
-                for c in f.result():
+
+            if self._ingest_pool is not None and len(live) > 1:
+                shards = [live[w::self.ingest_workers]
+                          for w in range(self.ingest_workers)]
+                futs = [self._ingest_pool.submit(run_shard, sh)
+                        for sh in shards if sh]
+                for f in futs:
+                    for c in f.result():
+                        counts_arr += c
+            else:
+                for c in run_shard(live):
                     counts_arr += c
-        else:
-            for i, env in live:
-                counts_arr += self._assemble_env(i, env, bounds, starts,
-                                                 values, ts, valid)
-        counts = [int(c) for c in counts_arr]
-        return make_raw_window(values, ts, valid), counts
+            counts = [int(c) for c in counts_arr]
+            if tallies is not None:
+                ms = 1e3 * np.asarray(tallies, np.float64).reshape(-1, 4)
+                drain_ms, ingest_ms, close_ms = ms[:, :3].sum(0).tolist()
+                sp.set_metadata(
+                    records=sum(counts), envs=len(live), drain_ms=drain_ms,
+                    ingest_ms=ingest_ms, close_ms=close_ms,
+                    queue_wait_ms=float(ms[:, 3].max(initial=0.0)),
+                    staged_bytes=values.nbytes + ts.nbytes + valid.nbytes)
+            return make_raw_window(values, ts, valid), counts
 
     def run_windows_scan(self, k: int) -> List[dict]:
         """Process the next ``k`` windows with ONE device dispatch."""
-        bounds = [self.window_bounds(self.window_index + j) for j in range(k)]
-        raw, counts = self.assemble_windows(bounds)
-        if self.fused_decide:
-            outs, t_dispatch, ver = self._dispatch_decide(raw, k)
-            return self._consume_decide(bounds, counts, outs, t_dispatch, ver)
-        feats, frames, t_dispatch = self._dispatch_scan(raw, k)
-        return self._consume_scan(bounds, counts, feats, frames, t_dispatch)
+        with spans.span("percepta.batch", k=k, window=self.window_index):
+            bounds = [self.window_bounds(self.window_index + j)
+                      for j in range(k)]
+            raw, counts = self.assemble_windows(bounds)
+            if self.fused_decide:
+                outs, t_dispatch, ver = self._dispatch_decide(raw, k)
+                return self._consume_decide(bounds, counts, outs,
+                                            t_dispatch, ver)
+            feats, frames, t_dispatch = self._dispatch_scan(raw, k)
+            return self._consume_scan(bounds, counts, feats, frames,
+                                      t_dispatch)
 
+    @spans.spanned("percepta.dispatch")
     def _dispatch_scan(self, raw, k: int):
         """Launch ONE ``run_many`` over a staged K-window batch (no block:
         JAX async dispatch returns futures; consumption blocks)."""
@@ -682,16 +723,17 @@ class PerceptaSystem:
             active=jnp.asarray(self._active) if self.elastic else None)
         return feats, frames, t_dispatch
 
+    @spans.spanned("percepta.consume")
     def _consume_scan(self, bounds, counts, feats, frames,
                       t_dispatch) -> List[dict]:
         """Block on a dispatched batch and run the batch host side
-        (Predictor, Forwarders, DB, metrics) in window order.
+        (Predictor, Forwarders, DB) in window order.
 
         The Predictor consumes the whole K-window stack in ONE jitted
         dispatch (``on_windows`` over the stacked device features — the
         same fusion ``run_many`` applies to the pipeline, applied to the
         decision path), then the per-window loop only slices numpy for
-        Forwarders/DB/metrics. ``batched_consume=False`` keeps the
+        Forwarders/DB/result rows. ``batched_consume=False`` keeps the
         per-window ``on_tick`` loop as the tested reference; both paths
         are bit-identical (asserted in tests/test_predictor_batch.py).
         """
@@ -765,8 +807,6 @@ class PerceptaSystem:
             # comparable to run_window's latency_s: amortized device +
             # predictor share of the batch plus this window's host work
             latency = batch_latency / k + (time.time() - t_host0)
-            self.metrics["tick_latency_s"].append(latency)
-            self.metrics["ingest_records"].append(counts[j])
             out.append({
                 "window": self.window_index - 1,
                 "records": counts[j],
@@ -794,21 +834,27 @@ class PerceptaSystem:
         bubble instead of delaying serving — the PR 3 priority-inversion
         lesson). Returns ``(outs, t_dispatch, policy_version)`` with the
         version that produced this batch's actions."""
-        if self.trainer is not None:
-            self._dstate = self.trainer.apply_pending(self._dstate)
-        ver = int(self.predictor.policy_version)
-        t_dispatch = time.time()
-        starts = jnp.zeros((k, self.cfg.n_envs), jnp.float32)
-        self.state, self._dstate, outs = self.pipeline.run_many_decide(
-            self.state, self._dstate, raw, starts)
-        if self.elastic:
-            # host mirror of the device-side post-scan update
-            # (prev_ok = prev_ok | active, see run_many_decide)
-            self._prev_ok = self._prev_ok | self._active
-        if self.trainer is not None:
-            self.trainer.dispatch(self._dstate)
+        with spans.span("percepta.dispatch"):
+            if self.trainer is not None:
+                with spans.span("percepta.train.apply"):
+                    self._dstate = self.trainer.apply_pending(self._dstate)
+            ver = int(self.predictor.policy_version)
+            t_dispatch = time.time()
+            starts = jnp.zeros((k, self.cfg.n_envs), jnp.float32)
+            with spans.span("percepta.fused_step"):
+                self.state, self._dstate, outs = \
+                    self.pipeline.run_many_decide(self.state, self._dstate,
+                                                  raw, starts)
+            if self.elastic:
+                # host mirror of the device-side post-scan update
+                # (prev_ok = prev_ok | active, see run_many_decide)
+                self._prev_ok = self._prev_ok | self._active
+            if self.trainer is not None:
+                with spans.span("percepta.train.dispatch"):
+                    self.trainer.dispatch(self._dstate)
         return outs, t_dispatch, ver
 
+    @spans.spanned("percepta.consume")
     def _consume_decide(self, bounds, counts, outs, t_dispatch,
                         version: int = 0) -> List[dict]:
         """Drain host sinks from the SMALL fused outputs.
@@ -818,9 +864,11 @@ class PerceptaSystem:
         (K, E, F) feature stack is fetched ONLY when a LogDB needs obs
         rows, and the (K, E, S, T) frames never leave the device (the
         fractions divide the exact counts, bit-identical to ``np.mean``
-        over the full frame)."""
+        over the full frame). Every window is forwarded, then every window
+        logged: each sink sees its windows in order."""
         k = len(bounds)
-        actions_b = np.asarray(outs.actions)   # first fetch blocks the batch
+        with spans.span("percepta.result_wait"):
+            actions_b = np.asarray(outs.actions)   # blocks on the batch
         batch_latency = time.time() - t_dispatch
         rewards_b = np.asarray(outs.rewards)
         obs_c = np.asarray(outs.observed)
@@ -840,30 +888,35 @@ class PerceptaSystem:
             n_rows = max(len(live), 1)
         else:
             rows, ids, n_rows = None, self.env_ids, self.cfg.n_envs
+        if rows is not None:
+            actions_b, rewards_b = actions_b[:, rows], rewards_b[:, rows]
+            if feat_np is not None:
+                feat_np = feat_np[:, rows]
+        # each window's own host seconds in the sinks, for its latency_s
+        host_s = [0.0] * k
+        if self.forwarders is not None:
+            with spans.span("percepta.forward"):
+                for j, (_, t_end) in enumerate(bounds):
+                    t0 = time.time()
+                    self.forwarders.dispatch_window(t_end, actions_b[j])
+                    host_s[j] += time.time() - t0
+        if self.db is not None:
+            with spans.span("percepta.log"):
+                for j, (_, t_end) in enumerate(bounds):
+                    t0 = time.time()
+                    self.db.append_many(ids, t_end, feat_np[j], actions_b[j],
+                                        rewards_b[j],
+                                        extra={"policy_version": version})
+                    host_s[j] += time.time() - t0
         denom = float(n_rows * self.cfg.n_streams * self.cfg.n_ticks)
         out = []
-        for j, (t_start, t_end) in enumerate(bounds):
-            t_host0 = time.time()
-            actions, rewards = actions_b[j], rewards_b[j]
-            feat_j = feat_np[j] if feat_np is not None else None
-            if rows is not None:
-                actions, rewards = actions[rows], rewards[rows]
-                if feat_j is not None:
-                    feat_j = feat_j[rows]
-            if self.forwarders is not None:
-                self.forwarders.dispatch_window(t_end, actions)
-            if self.db is not None:
-                self.db.append_many(ids, t_end, feat_j, actions,
-                                    rewards,
-                                    extra={"policy_version": version})
+        for j in range(k):
+            rewards = rewards_b[j]
             self.window_index += 1
-            latency = batch_latency / k + (time.time() - t_host0)
-            self.metrics["tick_latency_s"].append(latency)
-            self.metrics["ingest_records"].append(counts[j])
             out.append({
                 "window": self.window_index - 1,
                 "records": counts[j],
-                "latency_s": latency,
+                "latency_s": batch_latency / k + host_s[j],
                 "mean_reward": float(np.mean(rewards)) if rewards.size
                                else 0.0,
                 # exact integer counts / float64 size == np.mean over the
@@ -1172,6 +1225,10 @@ class PerceptaSystem:
                                       slot_times=slot_times)
 
     def run_windows(self, n: int, pump: bool = True) -> List[dict]:
+        with spans.span("percepta.run_windows", n=n):
+            return self._run_windows(n, pump)
+
+    def _run_windows(self, n: int, pump: bool) -> List[dict]:
         if self.mode in _ASYNC_MODES:
             return self._run_windows_async(n, pump)
         if self.mode in _SCAN_MODES:
