@@ -692,11 +692,10 @@ def bench_predictor_batch(quick=False):
 
 # Phase decomposition of the PR 3 overlap cell focused on the A term: twin
 # scan systems drain identical published batches, one through the legacy
-# chunk-list + global-lexsort accumulator (``ingest_fastpath=False``), one
-# through the arena-staged sorted-merge path (plus a 2-worker sharded
-# variant). D and C run identical code on every twin, so only the assemble
-# phase is compared; legs are interleaved with identical publish seeds and
-# bit-identity of every output row is asserted across all three twins.
+# chunk-list + global-lexsort accumulators (``ingest_fastpath=False``), one
+# through the fleet-wide pass. D and C run identical code on both twins, so
+# only the assemble phase is compared; legs are interleaved with identical
+# publish seeds and bit-identity of every output row is asserted.
 _INGEST_FASTPATH_SCRIPT = """
 import json, time
 import numpy as np
@@ -711,7 +710,7 @@ from repro.runtime.system import PerceptaSystem, SourceSpec
 E, S, K, M = 8, 8, 32, 64
 T, TICK_S, PER = 64, 15.0, 160
 
-def mk(fast, workers=1):
+def mk(fast):
     srcs = [SourceSpec(f"s{i}", "mqtt",
                        SimulatedDevice(f"st{i}", 60.0, base=3.0, seed=i))
             for i in range(S)]
@@ -724,8 +723,7 @@ def mk(fast, workers=1):
                      E, cfg.n_features, replay_capacity=64)
     return PerceptaSystem([f"b{i}" for i in range(E)], srcs, cfg, pred,
                           speedup=1e9, manual_time=True, mode="scan",
-                          scan_k=K, ingest_fastpath=fast,
-                          ingest_workers=workers)
+                          scan_k=K, ingest_fastpath=fast)
 
 def publish(s, n_windows, rng):
     # per-poll columns, time-sorted and honestly flagged -- the shape the
@@ -761,7 +759,7 @@ def measure(s, rows):
                     for r in out)
     return A, D, C
 
-sys_by = {"legacy": mk(False), "fast": mk(True), "fast_w2": mk(True, 2)}
+sys_by = {"legacy": mk(False), "fast": mk(True)}
 rows_by = {}
 legs = {name: [] for name in sys_by}
 for s in sys_by.values():
@@ -778,12 +776,8 @@ D = min(d for ls in legs.values() for _, d, _ in ls)
 C = min(c for ls in legs.values() for _, _, c in ls)
 a_ms = {name: round(min(a for a, _, _ in ls) / nb * 1e3, 1)
         for name, ls in legs.items()}
-ident = (rows_by["fast"] == rows_by["legacy"]
-         and rows_by["fast_w2"] == rows_by["legacy"])
-ms = {"close_fast": 0, "close_sort": 0, "close_lexsort": 0}
-for acc in sys_by["fast"].accumulators.values():
-    for k, v in acc.merge_stats.items():
-        ms[k] += v
+ident = rows_by["fast"] == rows_by["legacy"]
+ms = dict(sys_by["fast"].fleet.merge_stats)
 for s in sys_by.values():
     s.stop()
 n_records = E * S * N * PER                      # per leg, by construction
@@ -791,14 +785,14 @@ print(json.dumps({
     "bit_identical": bool(ident),
     "legacy_assemble_ms": a_ms["legacy"],
     "fast_assemble_ms": a_ms["fast"],
-    "fast_w2_assemble_ms": a_ms["fast_w2"],
     "assemble_speedup": round(a_ms["legacy"] / max(a_ms["fast"], 1e-9), 2),
     # ingest throughput through the fast assemble phase alone
     "records_per_s": round(n_records / (a_ms["fast"] * 1e-3 * nb), 1),
-    # every close on this cell should ride the promised-sorted fast path
+    # every close on this cell should order its groups without a
+    # timestamp sort
     "merge_stats_fast": ms,
     "sorted_fastpath_hit_rate": round(
-        ms["close_fast"] / max(sum(ms.values()), 1), 3),
+        1 - ms["close_lexsort"] / max(sum(ms.values()), 1), 3),
     "scan_phase_ms": {"assemble": a_ms["fast"],
                       "device": round(D / nb * 1e3, 1),
                       "consume": round(C / nb * 1e3, 1)},
@@ -822,8 +816,7 @@ def bench_ingest_fastpath(quick=False):
          cell["fast_assemble_ms"] * 1e3 / cell["cell"]["K"],
          f"assemble {cell['legacy_assemble_ms']:.1f} -> "
          f"{cell['fast_assemble_ms']:.1f} ms/batch "
-         f"({cell['assemble_speedup']:.1f}x; 2 workers "
-         f"{cell['fast_w2_assemble_ms']:.1f}) | "
+         f"({cell['assemble_speedup']:.1f}x) | "
          f"{cell['records_per_s']:.0f} records/s | sorted fast-path hit "
          f"{cell['sorted_fastpath_hit_rate']:.0%} | "
          f"bit_identical {cell['bit_identical']}")

@@ -96,16 +96,6 @@ ap.add_argument("--mode", default="scan",
                      "compositions split envs over the device mesh — "
                      "admissible because the registry rg-LRU policy is "
                      "certified per-env row-wise at registration")
-ap.add_argument("--ingest-workers", type=int, default=1,
-                help="shard host-side window assembly (drain -> ingest -> "
-                     "close) across N threads, envs striped by slot "
-                     "(live[w::N]) so ownership is deterministic under "
-                     "elastic churn; bit-identical to serial assembly "
-                     "(disjoint staging columns, order-independent count "
-                     "sums) and composes with the scan_async prefetcher. "
-                     "Worth it once E x records/window is large enough "
-                     "that assembly rivals the device phase — at this "
-                     "example's tiny E=4 it only adds thread overhead")
 args = ap.parse_args()
 SCAN_K = 2  # windows per scan-fused dispatch
 E = 4
@@ -134,8 +124,7 @@ hub = ForwarderHub([Forwarder("hvac", "mqtt", [0]),
                     Forwarder("ev-charger", "amqp", [1])])
 system = PerceptaSystem([f"bldg-{i}" for i in range(E)], sources, pcfg, pred,
                         forwarders=hub, db=db, speedup=4000.0,
-                        mode=args.mode, scan_k=SCAN_K,
-                        ingest_workers=args.ingest_workers)
+                        mode=args.mode, scan_k=SCAN_K)
 
 # --- ad-hoc batched request serving between ticks ---------------------------
 engine = ServeEngine(model, params, batch_slots=4, max_seq=64)

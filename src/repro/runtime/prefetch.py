@@ -5,7 +5,7 @@ build the (K, E, S, M) batch on the host, THEN dispatch ``run_many`` and
 wait. The device idles through every ``close_windows`` pass and the host
 idles through every device batch. This module pipelines the two: a pump
 thread assembles window batch *j+1* (clock advance -> receiver poll ->
-queue drain -> ``Accumulator.close_windows`` -> staged ``RawWindow``)
+queue drain -> ``FleetAccumulator.close_windows`` -> staged ``RawWindow``)
 while batch *j* executes on device via JAX's async dispatch; the Manager
 blocks only when it consumes batch *j*'s results.
 
@@ -24,9 +24,7 @@ deterministic batch-epoch handoff protocol:
     what sizes the system's rotating staging-buffer pool (at most four
     batches are ever alive: assembling, staged, taken, and the previous
     one in flight until it is consumed — see
-    ``PerceptaSystem._STAGE_DEPTH``), and ``ingest_workers`` composes
-    cleanly because the pump thread remains the sole pumper/drainer and
-    merely fans the per-env assembly work out to its worker pool;
+    ``PerceptaSystem._STAGE_DEPTH``);
   * the Manager consumes batches in epoch order and verifies the epoch tag
     on every handoff.
 

@@ -85,14 +85,18 @@ class EnvQueue:
             return False
 
     def drain(self, max_items: int = 1_000_000):
-        out = []
         with self._lock:
             self.drained_since = self._first_put if self._items else None
-            while self._items and len(out) < max_items:
-                it = self._items.popleft()
-                self._records -= _n(it)
-                out.append(it)
-            self.stats["dequeued"] += sum(_n(it) for it in out)
+            if len(self._items) <= max_items:
+                # everything: one list copy, no per-item bookkeeping
+                out = list(self._items)
+                self._items.clear()
+                n = self._records
+            else:
+                out = [self._items.popleft() for _ in range(max_items)]
+                n = sum(_n(it) for it in out)
+            self._records -= n
+            self.stats["dequeued"] += n
         return out
 
     def qsize(self):

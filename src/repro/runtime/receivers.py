@@ -80,10 +80,9 @@ class Receiver(threading.Thread):
         readings cross the receiver boundary as two NumPy columns plus a
         sortedness flag, no per-reading Python). ``sorted_ts`` is computed
         here — the receiver is the one place that sees the columns exactly
-        once — and lets the Accumulator's sorted-merge close skip both its
-        verification pass and its sort. Device jitter can reorder adjacent
-        readings (jitter_s > interval_s), so the flag is measured, never
-        assumed.
+        once; device jitter can reorder adjacent readings (jitter_s >
+        interval_s), so the flag is measured, never assumed. The window
+        close checks order itself and does not read it.
     When both are given the batch path wins; stats count logical readings
     either way (bytes on the batch path are the 16-byte binary-equivalent
     per reading, so load accounting stays comparable across paths).

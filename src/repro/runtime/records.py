@@ -42,12 +42,12 @@ class RecordBatch:
 
     ``sorted_ts`` is the producer's sortedness promise: ``True`` means each
     stream's timestamp subsequence is non-decreasing (for a single-stream
-    batch, simply that ``timestamps`` is non-decreasing), which lets the
-    Accumulator's sorted-merge close skip its O(n) verification pass.
-    ``None`` (default) means unknown — consumers verify cheaply on append.
-    It must only be set ``True`` when actually true; ``False``/``None`` are
-    always safe. Receivers compute it per poll; queue truncation preserves
-    it (a prefix of a sorted column is sorted).
+    batch, simply that ``timestamps`` is non-decreasing). ``None``
+    (default) means unknown. It must only be set ``True`` when actually
+    true; ``False``/``None`` are always safe. Receivers compute it per
+    poll; queue truncation preserves it (a prefix of a sorted column is
+    sorted). The window close verifies order itself and does not read
+    it.
     """
     env_id: str
     streams: tuple                # stream-name table, indexed by stream_ids

@@ -14,9 +14,12 @@ publish. One ``run_windows_scan`` batch of a fused-decide mode records::
     percepta.run_windows        n
       percepta.batch            k, window (the batch's first window index)
         percepta.assemble       when tracing: records, envs, drain_ms,
-                                ingest_ms, close_ms (summed over envs),
+                                ingest_ms (gather + count), close_ms,
                                 queue_wait_ms (the oldest drained reading's
-                                wait), staged_bytes (the (K, E, S, M) triple)
+                                wait), staged_bytes (the (K, E, S, M)
+                                triple); on the fleet pass sort_fallbacks
+                                (1 when the close ordered by timestamp) and
+                                carried (records left pending)
         percepta.dispatch
           percepta.train.apply      trainer.apply_pending
           percepta.fused_step       run_many_decide, with the triple's transfer

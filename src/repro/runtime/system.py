@@ -13,8 +13,8 @@ assemble the device batch, run the (fused or modular) Percepta tick, run the
 Predictor, forward the decisions, log everything.
 
 ``mode="scan"`` switches the Manager loop to the scan-fused engine: queues
-are drained once per batch, each env's Accumulator closes K consecutive
-windows into a stacked (K, E, S, M) RawWindow, and ONE device dispatch
+are drained once per batch, K consecutive windows of every env are closed
+into a stacked (K, E, S, M) RawWindow, and ONE device dispatch
 (``PerceptaPipeline.run_many``) processes all K windows with the state
 carried on device. The decision path is batched the same way: the
 Predictor consumes the stacked (K, E, F) features in ONE jitted dispatch
@@ -123,17 +123,16 @@ there the columnar path (which skips the encode/decode) is the
 higher-fidelity one.
 
 The host ingest fast path (``ingest_fastpath=True``, default) makes batch
-assembly allocation- and sort-free in the steady state: Accumulators
-append into preallocated per-stream arenas, receivers attach a measured
-per-poll sortedness flag that lets ``close_windows`` bucket by
-``searchsorted`` alone (a stable per-stream argsort handles unsorted
-arrivals — identical ordering to the legacy global lexsort), and
-``assemble_windows`` closes every env directly into a rotating pool of
+assembly one fleet-wide pass in every mode: ``assemble_windows`` drains
+every live env, gathers all drained batches into one set of columns keyed
+by (slot, stream) and closes them with a handful of NumPy calls
+(``accumulator.FleetAccumulator``) straight into a rotating pool of
 preallocated (K, E, S, M) staging buffers (host numpy; donation rules
-untouched). ``ingest_workers=N`` additionally partitions the per-env
-assembly across N persistent threads with deterministic slot-striped
-ownership. Every combination is bit-identical to the legacy path —
-windows, stats, tie order, drop accounting (tests/test_ingest_fastpath).
+untouched); the per-window ``"fused"`` mode assembles its one window the
+same way. The per-env Accumulators keep their stats there.
+``ingest_fastpath=False`` runs one legacy chunk-list + lexsort Accumulator
+per env, the reference the fleet pass is bit-identical to — windows,
+stats, tie order, drop accounting (tests/test_ingest_fastpath).
 """
 from __future__ import annotations
 
@@ -150,13 +149,13 @@ import numpy as np
 from repro.core import PerceptaPipeline, PipelineConfig
 from repro.core.frame import make_raw_window
 from repro.runtime import spans
-from repro.runtime.accumulator import Accumulator
+from repro.runtime.accumulator import Accumulator, FleetAccumulator
 from repro.runtime.forwarder import ForwarderHub
 from repro.runtime.predictor import Predictor
 from repro.runtime.prefetch import WindowPrefetcher
 from repro.runtime.queues import QueueBroker
 from repro.runtime.receivers import Receiver, SimulatedDevice
-from repro.runtime.records import RecordBatch, count_records
+from repro.runtime.records import RecordBatch
 from repro.runtime.translator import Translator
 
 # Manager-loop mode -> device-pipeline mode: the async modes reuse the scan
@@ -220,7 +219,6 @@ class PerceptaSystem:
                  policy=None,
                  env_slots: Optional[int] = None,
                  elastic: bool = False,
-                 ingest_workers: int = 1,
                  ingest_fastpath: bool = True):
         # manual_time: the virtual clock only advances when run_windows
         # closes a window — deterministic under arbitrary jit-compile stalls
@@ -364,27 +362,11 @@ class PerceptaSystem:
         self.scan_k = max(1, int(scan_k))
         assert ingest in ("columnar", "records"), ingest
         self.ingest = ingest
-        # ingest_fastpath: per-stream arena staging + sorted-merge window
-        # bucketing in every Accumulator (bit-identical to the legacy
-        # chunk-list + global-lexsort path, which False keeps alive for
-        # before/after benchmarking and parity tests)
+        # ingest_fastpath: one fleet-wide assembly pass (bit-identical to
+        # the per-env legacy chunk-list + global-lexsort Accumulators,
+        # which False keeps alive for before/after benchmarking and parity
+        # tests)
         self.ingest_fastpath = bool(ingest_fastpath)
-        # ingest_workers=N: assemble_windows partitions the live envs over
-        # N persistent workers with deterministic slot-striped ownership;
-        # per-env work (drain -> ingest -> close into disjoint staging
-        # rows) is env-isolated, and the per-window record counts are
-        # summed with integer adds, so results are bit-identical to the
-        # serial loop. The pump thread stays the only pumper/drainer in
-        # async modes — workers only parallelize the per-env assembly the
-        # pump (or Manager) already owns, so the prefetcher's epoch
-        # protocol is untouched.
-        self.ingest_workers = max(1, int(ingest_workers))
-        self._ingest_pool = None
-        if self.ingest_workers > 1:
-            from concurrent.futures import ThreadPoolExecutor
-            self._ingest_pool = ThreadPoolExecutor(
-                max_workers=self.ingest_workers,
-                thread_name_prefix="percepta-ingest")
         # (K, E, S, M)-keyed pool of reusable host staging buffers for
         # assemble_windows (see _staging_buffers)
         self._stage_pool: Dict[tuple, dict] = {}
@@ -446,6 +428,12 @@ class PerceptaSystem:
         self.accumulators: Dict[str, Accumulator] = {}
         for env in env_ids:
             self._register_env(env)
+        # the fast path closes every env in one pass; the per-env
+        # Accumulators then keep only their stats
+        self.fleet: Optional[FleetAccumulator] = None
+        if self.ingest_fastpath:
+            self.fleet = FleetAccumulator(self._stream_names,
+                                          pipeline_cfg.max_samples)
 
     def _register_env(self, env_id: str) -> None:
         """Wire one env into every source Receiver and give it its own
@@ -468,8 +456,7 @@ class PerceptaSystem:
             else:
                 r.subscribe(env_id, on_payload)
         self.accumulators[env_id] = Accumulator(env_id, self._stream_names,
-                                                self.cfg.max_samples,
-                                                fastpath=self.ingest_fastpath)
+                                                self.cfg.max_samples)
 
     def _live_slots(self) -> List[tuple]:
         """``[(slot_row, env_id), ...]`` of the live envs, slot order.
@@ -505,11 +492,6 @@ class PerceptaSystem:
             self._prefetcher.stop()
         if self.trainer is not None:
             self.trainer.close()
-        if self._ingest_pool is not None:
-            self._ingest_pool.shutdown(wait=True)
-            # a post-stop run_windows call degrades to the serial loop
-            # instead of submitting to a dead executor
-            self._ingest_pool = None
 
     # --- synchronous operation (benchmarks / tests) ---------------------------
     def pump_receivers(self):
@@ -519,24 +501,11 @@ class PerceptaSystem:
     def run_window(self) -> dict:
         """Process one closed window across all environments."""
         t_start, t_end = self.window_bounds()
-        E, S, M = self.cfg.n_envs, self.cfg.n_streams, self.cfg.max_samples
-
-        n_new = 0
-        for env in self.env_ids:
-            recs = self.broker.queue_for(env).drain()
-            n_new += count_records(recs)
-            self.accumulators[env].ingest(recs)
-
-        values = np.zeros((E, S, M), np.float32)
-        ts = np.zeros((E, S, M), np.float32)
-        valid = np.zeros((E, S, M), bool)
-        for i, env in enumerate(self.env_ids):
-            v, t, m = self.accumulators[env].close_window(t_start, t_end,
-                                                          rebase=True)
-            values[i], ts[i], valid[i] = v, t, m
+        E = self.cfg.n_envs
+        (values, ts, valid), counts = self._assemble([(t_start, t_end)])
 
         t_proc0 = time.time()
-        raw = make_raw_window(values, ts, valid)
+        raw = make_raw_window(values[0], ts[0], valid[0])
         # window-relative time: timestamps were rebased to this window's
         # start, so the device sees window_start = 0 (float32-exact on any
         # horizon); absolute time stays host-side (t_end below)
@@ -560,7 +529,7 @@ class PerceptaSystem:
         self.window_index += 1
         return {
             "window": self.window_index - 1,
-            "records": n_new,
+            "records": counts[0],
             "latency_s": latency,
             "mean_reward": float(np.mean(rewards)),
             "observed_frac": float(np.asarray(frame.observed).mean()),
@@ -603,15 +572,12 @@ class PerceptaSystem:
 
     def _assemble_env(self, slot: int, env: str, bounds, starts,
                       values, ts, valid, tally: list = None) -> np.ndarray:
-        """Drain, count, ingest and close ONE env into its staging rows.
+        """Drain, count, ingest and close ONE env into its staging rows
+        (``ingest_fastpath=False``: the per-env legacy reference).
 
-        The unit of work ``ingest_workers`` partitions: everything touched
-        here — the env's queue, its Accumulator, column ``slot`` of the
-        staging buffers — belongs to exactly one env, so concurrent calls
-        for different envs share nothing. Given a ``tally`` (a traced
-        batch), the steps are clocked: drain, count + ingest and close
-        seconds add to ``tally[0:3]``, the longest queue wait is kept in
-        ``tally[3]``."""
+        Given a ``tally`` (a traced batch), the steps are clocked: drain,
+        count + ingest and close seconds add to ``tally[0:3]``, the longest
+        queue wait is kept in ``tally[3]``."""
         timed = tally is not None
         q = self.broker.queue_for(env)
         t0 = time.perf_counter() if timed else 0.0
@@ -632,6 +598,34 @@ class PerceptaSystem:
                 tally[3] = max(tally[3], t1 - q.drained_since)
         return c
 
+    def _assemble_fleet(self, live, bounds, starts, out,
+                        tally: list = None) -> np.ndarray:
+        """Drain every live env, then gather and close the whole fleet in
+        one pass (``FleetAccumulator``). ``tally`` as in
+        :meth:`_assemble_env`."""
+        timed = tally is not None
+        t0 = time.perf_counter() if timed else 0.0
+        drained = []
+        for slot, env in live:
+            q = self.broker.queue_for(env)
+            items = q.drain()
+            if items:
+                drained.append((slot, items))
+            if timed and q.drained_since is not None:
+                tally[3] = max(tally[3],
+                               time.perf_counter() - q.drained_since)
+        t1 = time.perf_counter() if timed else 0.0
+        stats = {slot: self.accumulators[env].stats for slot, env in live}
+        counts = self.fleet.ingest(drained, starts, stats)
+        t2 = time.perf_counter() if timed else 0.0
+        self.fleet.close_windows(bounds, out, stats)
+        if timed:
+            t3 = time.perf_counter()
+            tally[0] += t1 - t0
+            tally[1] += t2 - t1
+            tally[2] += t3 - t2
+        return counts
+
     def assemble_windows(self, bounds) -> tuple:
         """Drain queues once and stack K closed windows per env — one pass
         straight into preallocated (K, E, S, M) staging buffers.
@@ -641,60 +635,52 @@ class PerceptaSystem:
         contain its timestamp (clipped to the batch, so the counts sum to
         the drain total — mirroring fused mode's per-window ingest numbers
         for consumers like dead-source detection). Per-env isolation is
-        structural: each env's records flow queue -> its own Accumulator ->
-        column i of the staging stack; no cross-env array is ever indexed
-        by more than one env. Inactive/free slots keep their all-invalid
-        zero rows: on device their state updates are natural no-ops and
-        outputs are masked.
+        structural: each record is keyed by its env's slot from queue to
+        staging row ``slot``; no record ever lands in another env's row.
+        Inactive/free slots keep their all-invalid zero rows: on device
+        their state updates are natural no-ops and outputs are masked.
 
-        With ``ingest_workers=N`` the live envs are partitioned
-        slot-striped across N persistent workers (env at live position p is
-        owned by worker p mod N — deterministic for a given membership).
-        Each env's drain -> ingest -> close sequence is unchanged and
-        env-isolated, and the per-window counts are summed with integer
-        adds, so the result is bit-identical to the serial loop.
+        The fast path (``ingest_fastpath=True``) drains every live env and
+        then gathers and closes the whole fleet with one
+        ``FleetAccumulator`` pass; ``ingest_fastpath=False`` runs the
+        per-env legacy Accumulators, the bit-identity reference.
         """
+        (values, ts, valid), counts = self._assemble(bounds)
+        return make_raw_window(values, ts, valid), counts
+
+    def _assemble(self, bounds) -> tuple:
+        """:meth:`assemble_windows` on the host: the staging triple and the
+        per-window counts."""
         with spans.span("percepta.assemble") as sp:
-            E = self.cfg.n_envs
             K = len(bounds)
             starts = np.asarray([b[0] for b in bounds], np.float64)
             live = self._live_slots()
-            values, ts, valid = self._staging_buffers(K, E)
-            counts_arr = np.zeros(K, np.int64)
-            # one tally per shard while a profiler records (see
-            # _assemble_env); None leaves the steps unclocked
-            tallies = [] if spans.tracing() else None
-
-            def run_shard(shard):
-                tally = None
-                if tallies is not None:
-                    tally = [0.0, 0.0, 0.0, 0.0]
-                    tallies.append(tally)
-                return [self._assemble_env(i, env, bounds, starts,
-                                           values, ts, valid, tally)
-                        for i, env in shard]
-
-            if self._ingest_pool is not None and len(live) > 1:
-                shards = [live[w::self.ingest_workers]
-                          for w in range(self.ingest_workers)]
-                futs = [self._ingest_pool.submit(run_shard, sh)
-                        for sh in shards if sh]
-                for f in futs:
-                    for c in f.result():
-                        counts_arr += c
+            values, ts, valid = self._staging_buffers(K, self.cfg.n_envs)
+            # the steps are clocked only while a profiler records
+            tally = [0.0, 0.0, 0.0, 0.0] if spans.tracing() else None
+            if self.fleet is not None:
+                counts_arr = self._assemble_fleet(
+                    live, bounds, starts, (values, ts, valid), tally)
             else:
-                for c in run_shard(live):
-                    counts_arr += c
+                counts_arr = np.zeros(K, np.int64)
+                for i, env in live:
+                    counts_arr += self._assemble_env(
+                        i, env, bounds, starts, values, ts, valid, tally)
             counts = [int(c) for c in counts_arr]
-            if tallies is not None:
-                ms = 1e3 * np.asarray(tallies, np.float64).reshape(-1, 4)
-                drain_ms, ingest_ms, close_ms = ms[:, :3].sum(0).tolist()
+            if tally is not None:
+                drain_ms, ingest_ms, close_ms, wait_ms = (1e3 * t
+                                                          for t in tally)
+                meta = {}
+                if self.fleet is not None:
+                    meta = {"sort_fallbacks": self.fleet.sort_fallback,
+                            "carried": self.fleet.pending()}
                 sp.set_metadata(
                     records=sum(counts), envs=len(live), drain_ms=drain_ms,
                     ingest_ms=ingest_ms, close_ms=close_ms,
-                    queue_wait_ms=float(ms[:, 3].max(initial=0.0)),
-                    staged_bytes=values.nbytes + ts.nbytes + valid.nbytes)
-            return make_raw_window(values, ts, valid), counts
+                    queue_wait_ms=wait_ms,
+                    staged_bytes=values.nbytes + ts.nbytes + valid.nbytes,
+                    **meta)
+            return (values, ts, valid), counts
 
     def run_windows_scan(self, k: int) -> List[dict]:
         """Process the next ``k`` windows with ONE device dispatch."""
@@ -1013,6 +999,8 @@ class PerceptaSystem:
             r.unsubscribe(env_id)
         self.broker.remove(env_id)
         self.accumulators.pop(env_id).reset()
+        if self.fleet is not None:
+            self.fleet.discard(slot)
         self._slot_env[slot] = None
         self._active[slot] = False
         self._prev_ok[slot] = False

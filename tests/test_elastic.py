@@ -24,6 +24,7 @@ from repro.core import PipelineConfig
 from repro.core.reward import energy_reward_spec
 from repro.runtime.predictor import ActionSpace, Predictor, linear_policy
 from repro.runtime.receivers import SimulatedDevice
+from repro.runtime.records import RecordBatch
 from repro.runtime.system import PerceptaSystem, SourceSpec
 
 # every engine the elastic refactor touches that runs in-process (the
@@ -35,7 +36,8 @@ ELASTIC_MODES = ("scan", "scan_sharded", "scan_async", "scan_fused_decide",
 STABLE = ["s0", "s1", "s2"]      # attached at construction, never touched
 
 
-def _mk(env_ids, slots=None, elastic=False, mode="scan", scan_k=3, cap=16):
+def _mk(env_ids, slots=None, elastic=False, mode="scan", scan_k=3, cap=16,
+        **kw):
     # off-tick reading intervals (9.7 / 31.3 s): no reading ever lands
     # exactly on a window boundary, so window membership can't flip on a
     # float comparison between runs
@@ -52,7 +54,7 @@ def _mk(env_ids, slots=None, elastic=False, mode="scan", scan_k=3, cap=16):
                      n, cfg.n_features, replay_capacity=cap)
     return PerceptaSystem(list(env_ids), srcs, cfg, pred, speedup=5000.0,
                           manual_time=True, mode=mode, scan_k=scan_k,
-                          env_slots=slots, elastic=elastic)
+                          env_slots=slots, elastic=elastic, **kw)
 
 
 def _strip(results):
@@ -157,6 +159,79 @@ def test_attach_env_grows_full_pool():
 
 
 # --------------------------------------------------------------------------
+# Carried records: readings delivered ahead of the batch that takes them
+# --------------------------------------------------------------------------
+
+def _deliver_ahead(system, env_ids, w0, n_windows, seed):
+    """Publish 12 readings a window per env and stream for windows
+    [w0, w0 + n_windows) straight to the queues, as one sorted batch each
+    (no receiver poll), so a batch ending before them carries the rest."""
+    rng = np.random.RandomState(seed)
+    t0 = system.window_bounds(w0)[0]
+    for env in env_ids:
+        for src in system.sources:
+            n = 12 * n_windows
+            ts = np.sort(rng.uniform(t0, t0 + n_windows * system.window_s,
+                                     n))
+            system.broker.publish(RecordBatch.from_columns(
+                env, src.device.stream, ts, rng.normal(5.0, 1.0, n),
+                sorted_ts=True))
+
+
+def test_detach_discards_carried_records_as_reset_does():
+    """Detaching an env with carried records discards exactly what the
+    per-env legacy Accumulator's ``reset`` returns; the env re-attaches to
+    an empty slot, and every later batch matches the legacy system."""
+    fast = _mk(STABLE, slots=4, elastic=True)
+    slow = _mk(STABLE, slots=4, elastic=True, ingest_fastpath=False)
+    rows = {}
+    for s in (fast, slow):
+        _deliver_ahead(s, STABLE, 0, 9, seed=0)
+        rows[s] = _strip(s.run_windows(3, pump=False))
+    slot = fast._slot_env.index("s1")
+    before = fast.fleet.pending()
+    assert before > 0
+    fast.detach_env("s1")
+    n_slow = slow.accumulators["s1"].reset()
+    slow.detach_env("s1")
+    assert before - fast.fleet.pending() == n_slow > 0
+    for s in (fast, slow):
+        assert s.attach_env("s1") == slot
+    assert fast.fleet.discard(slot) == 0
+    for s in (fast, slow):
+        _deliver_ahead(s, ["s1"], 3, 6, seed=1)
+        rows[s] += _strip(s.run_windows(6, pump=False))
+    assert rows[fast] == rows[slow]
+    ea, eb = fast.export_replay("s"), slow.export_replay("s")
+    for k, v in ea.items():
+        eq = (np.asarray(v) == np.asarray(eb[k]))
+        assert eq if isinstance(eq, bool) else eq.all(), k
+    fast.stop(), slow.stop()
+
+
+@pytest.mark.parametrize("mode", ("scan", "scan_fused_decide"))
+def test_resize_keeps_carried_records(mode):
+    """A pool regrown (4 -> 8 slots) while records are carried: the live
+    envs' results stay bit-identical to a dense fixed-E system."""
+    dense = _mk(STABLE, mode=mode)
+    el = _mk(STABLE, slots=4, elastic=True, mode=mode)
+    out = {}
+    for s in (dense, el):
+        _deliver_ahead(s, STABLE, 0, 9, seed=2)
+        out[s] = _strip(s.run_windows(3, pump=False))
+    assert el.fleet.pending() > 0
+    el.resize()
+    assert el.env_slots == 8
+    for s in (dense, el):
+        _deliver_ahead(s, STABLE, 9, 3, seed=3)
+        out[s] += _strip(s.run_windows(9, pump=False))
+    assert out[dense] == out[el]
+    assert el.fleet.pending() == 0
+    _assert_rows_equal(dense.export_replay("s"), el.export_replay("s"))
+    dense.stop(), el.stop()
+
+
+# --------------------------------------------------------------------------
 # Property: random churn schedules never perturb the stable envs' rows
 # --------------------------------------------------------------------------
 
@@ -232,6 +307,7 @@ from repro.core import PipelineConfig
 from repro.core.reward import energy_reward_spec
 from repro.runtime.predictor import ActionSpace, Predictor, linear_policy
 from repro.runtime.receivers import SimulatedDevice
+from repro.runtime.records import RecordBatch
 from repro.runtime.system import PerceptaSystem, SourceSpec
 import jax
 assert len(jax.devices()) == 8, jax.devices()

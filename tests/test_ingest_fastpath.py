@@ -1,9 +1,8 @@
 """Host ingest fast path: bit-identity with the legacy path everywhere.
 
-The arena-staged, sorted-merge, one-pass-assembly ingest
-(``ingest_fastpath=True``, the default) and its ``ingest_workers``
-sharding must reproduce the legacy chunk-list + global-lexsort path
-bit for bit: windows, stats, tie order, overflow truncation, and the
+The fleet-wide assembly pass (``ingest_fastpath=True``, the default) must
+reproduce the per-env legacy chunk-list + global-lexsort Accumulators bit
+for bit: windows, stats, tie order, overflow truncation, and the
 replay/LogDB sinks — across codecs, ``ingest="records"`` vs
 ``"columnar"``, elastic masked pools, and scan/async/fused modes.
 """
@@ -12,13 +11,13 @@ import pytest
 
 from repro.core import PipelineConfig
 from repro.core.reward import energy_reward_spec
-from repro.runtime.accumulator import Accumulator
+from repro.runtime.accumulator import Accumulator, FleetAccumulator
 from repro.runtime.db import LogDB
 from repro.runtime.predictor import ActionSpace, Predictor, linear_policy
 from repro.runtime.queues import _head
 from repro.runtime.receivers import Receiver, SimulatedDevice
 from repro.runtime.records import Record, RecordBatch
-from repro.runtime.system import PerceptaSystem, SourceSpec
+from repro.runtime.system import PerceptaSystem, SourceSpec, _window_counts
 from repro.runtime.translator import Translator
 from repro.testing import given, settings, st
 
@@ -62,67 +61,125 @@ def _mixed_items(rng, n=150, max_t=480.0):
 
 
 def _close_twice(acc):
-    """Two close rounds: the second exercises the retained tail (the
-    arena's self-healing sortedness) and fresh stats accumulation."""
+    """Two close rounds: the second exercises the retained tail and fresh
+    stats accumulation."""
     r1 = acc.close_windows(BOUNDS, rebase=True)
-    r2 = acc.close_windows(LATER_BOUNDS, rebase=False)
+    r2 = acc.close_windows(LATER_BOUNDS, rebase=True)
     return r1, r2
 
 
+def _new_stats():
+    return {"records": 0, "unknown_stream": 0, "overflow": 0}
+
+
+def _fleet_close_twice(items, max_samples):
+    """``_close_twice`` through the fleet pass, for one env in slot 0:
+    the two rounds' (K, S, M) triples and the env's stats."""
+    fleet = FleetAccumulator(STREAMS, max_samples)
+    stats = {0: _new_stats()}
+    fleet.ingest([(0, items)], np.asarray([b[0] for b in BOUNDS]), stats)
+    rounds = []
+    for bounds in (BOUNDS, LATER_BOUNDS):
+        out = _zeros(len(bounds), 1, max_samples)
+        fleet.close_windows(bounds, out, stats)
+        rounds.append(tuple(a[:, 0] for a in out))
+    return rounds, stats[0]
+
+
 @pytest.mark.parametrize("max_samples", [4, 16])   # 4 forces overflow
-def test_sorted_merge_equals_lexsort_bit_for_bit(rng, max_samples):
+def test_fleet_pass_equals_lexsort_bit_for_bit(rng, max_samples):
     items = _mixed_items(rng)
-    fast = Accumulator("env", STREAMS, max_samples, fastpath=True)
-    slow = Accumulator("env", STREAMS, max_samples, fastpath=False)
-    fast.ingest(items)
+    slow = Accumulator("env", STREAMS, max_samples)
     slow.ingest(items)
-    for ra, rb in zip(_close_twice(fast), _close_twice(slow)):
+    rounds, stats = _fleet_close_twice(items, max_samples)
+    for ra, rb in zip(rounds, _close_twice(slow)):
         for x, y in zip(ra, rb):
             assert x.dtype == y.dtype and (x == y).all()
-    assert fast.stats == slow.stats
-    assert fast.merge_stats["close_lexsort"] == 0
-    assert slow.merge_stats["close_fast"] == 0
+    assert stats == slow.stats
 
 
 @given(seed=st.integers(0, 10_000), max_samples=st.sampled_from((3, 8, 64)))
 @settings(max_examples=25, deadline=None)
-def test_property_sorted_merge_vs_lexsort_parity(seed, max_samples):
+def test_property_fleet_pass_vs_lexsort_parity(seed, max_samples):
     """Random record streams (random batching, sortedness, ties, overflow):
-    the sorted-merge close and the global-lexsort close agree bit for bit
-    on every output array and every stat, across two close rounds."""
+    the fleet pass and the global-lexsort close agree bit for bit on every
+    output array and every stat, across two close rounds."""
     rng = np.random.RandomState(seed)
     items = _mixed_items(rng, n=30 + rng.randint(120))
-    fast = Accumulator("env", STREAMS, max_samples, fastpath=True)
-    slow = Accumulator("env", STREAMS, max_samples, fastpath=False)
-    fast.ingest(items)
+    slow = Accumulator("env", STREAMS, max_samples)
     slow.ingest(items)
-    for ra, rb in zip(_close_twice(fast), _close_twice(slow)):
+    rounds, stats = _fleet_close_twice(items, max_samples)
+    for ra, rb in zip(rounds, _close_twice(slow)):
         for x, y in zip(ra, rb):
             assert x.dtype == y.dtype and (x == y).all()
-    assert fast.stats == slow.stats
+    assert stats == slow.stats
 
 
-def test_out_of_order_arrivals_sort_then_heal(rng):
-    """Unsorted arrivals take the argsort fallback exactly once: the
-    retained tail is stored sorted, so the NEXT close is fast again."""
-    acc = Accumulator("env", ["s"], 64)
-    ts = rng.uniform(0, 480.0, 50)            # unsorted, spans both closes
-    acc.ingest_batch(RecordBatch.from_columns("env", "s", ts, ts))
-    acc.close_windows(BOUNDS)
-    assert acc.merge_stats == {"close_fast": 0, "close_sort": 1,
-                               "close_lexsort": 0}
-    acc.close_windows(LATER_BOUNDS)
-    assert acc.merge_stats["close_fast"] == 1  # tail healed to sorted
+@pytest.mark.parametrize("arrival,how", [
+    ("env_major", "close_fast"),     # one run per (window, env, stream)
+    ("env_reversed", "close_fast"),  # runs need not be in key order
+    ("interleaved", "close_sort"),   # a group split over runs
+])
+def test_grouped_input_closes_without_timestamp_sort(arrival, how):
+    """Time-ordered input never takes the timestamp sort: rows already
+    grouped by staging row close with no sort at all, split groups with
+    one stable sort by group; the staging bytes are the same."""
+    E, M = 3, 8
+    ts = np.arange(6, dtype=np.float64) * 15.0
+    items = {e: [RecordBatch.from_columns(f"e{e}", "temp_c", ts, ts + e)]
+             for e in range(E)}
+    if arrival == "env_reversed":
+        drained = [(e, items[e]) for e in reversed(range(E))]
+    elif arrival == "env_major":
+        drained = list(items.items())
+    else:      # each env's rows arrive as two batches, a window apart
+        drained = [(e, [RecordBatch.from_columns(f"e{e}", "temp_c",
+                                                 ts[:3], ts[:3] + e)])
+                   for e in range(E)]
+        drained += [(e, [RecordBatch.from_columns(f"e{e}", "temp_c",
+                                                  ts[3:], ts[3:] + e)])
+                    for e in range(E)]
+    fleet = FleetAccumulator(STREAMS, M)
+    stats = {e: _new_stats() for e in range(E)}
+    fleet.ingest(drained, np.zeros(1), stats)
+    out = _zeros(1, E, M)
+    fleet.close_windows([(0.0, WIN)], out, stats)
+    assert fleet.merge_stats[how] == 1 and fleet.sort_fallback == 0
+    for e in range(E):
+        assert out[0][0, e, 1, :6].tolist() == (ts + e).tolist()
+        assert stats[e]["records"] == 6
 
 
-def test_sorted_flag_skips_verification_and_buckets_fast():
-    acc = Accumulator("env", ["s"], 64)
-    ts = np.arange(10, dtype=np.float64) * 30.0
-    acc.ingest_batch(RecordBatch.from_columns("env", "s", ts, ts,
-                                              sorted_ts=True))
-    v, t, m = acc.close_windows(BOUNDS)
-    assert acc.merge_stats["close_fast"] == 1
-    assert int(m.sum()) == 10 and acc.stats["records"] == 10
+@pytest.mark.parametrize("kind", ["batch", "multi_stream", "records"])
+def test_fleet_call_of_unknown_streams_only(kind):
+    """A call whose drained items all name unknown streams counts them per
+    env, per window and stages nothing, as the per-env Accumulator
+    does; the next call is unaffected."""
+    ts = [10.0, 20.0, 150.0]
+    if kind == "batch":
+        items = [RecordBatch.from_columns("e", "zz", ts, ts)]
+    elif kind == "multi_stream":
+        items = [RecordBatch.from_records(
+            [Record("e", nm, t, t) for nm, t in zip(["zz", "yy", "zz"], ts)])]
+    else:
+        items = [Record("e", "zz", t, t) for t in ts]
+    bounds = [(0.0, 100.0), (100.0, 200.0)]
+    starts = np.asarray([b[0] for b in bounds])
+    fleet = FleetAccumulator(STREAMS, 4)
+    stats = {0: _new_stats(), 1: _new_stats()}
+    counts = fleet.ingest([(1, items)], starts, stats)
+    out = _zeros(2, 2, 4)
+    fleet.close_windows(bounds, out, stats)
+    ref = Accumulator("e", STREAMS, 4)
+    ref.ingest(items)
+    assert counts.tolist() == _window_counts(items, starts).tolist() == [2, 1]
+    assert stats[1] == ref.stats and stats[0] == _new_stats()
+    assert not any(a.any() for a in out) and fleet.pending() == 0
+    more = [RecordBatch.from_columns("e", "temp_c", [250.0], [1.0])]
+    fleet.ingest([(1, more)], starts + 200.0, stats)
+    fleet.close_windows([(a + 200.0, b + 200.0) for a, b in bounds], out,
+                        stats)
+    assert out[2][0, 1, 1, 0] and stats[1]["records"] == 1
 
 
 def test_sorted_flag_propagates_receiver_translator_queue():
@@ -145,6 +202,150 @@ def test_sorted_flag_propagates_receiver_translator_queue():
     # default stays "unknown", never a false promise
     b2 = tr.translate_batch("e", "s", [2.0, 1.0], [3.0, 4.0])
     assert b2.sorted_ts is None
+
+
+# --------------------------------------------------------------------------
+# Fleet pass: one whole-fleet close == one Accumulator per env
+# --------------------------------------------------------------------------
+
+WIN = 100.0
+T0 = 1e8        # float32 spacing is 8 s here: rebasing must subtract first
+
+
+def _fleet_calls(seed, E, K, n_calls, ordered):
+    """Per batch of K windows (``WIN`` long), each env's drained items.
+
+    Rows fall from before the batch's first window start (stale, masked
+    invalid) to two batches past its last end (carried across two
+    closes), on a 5 s grid (timestamp ties, exact window bounds) plus
+    0 or 0.125 s, at ``T0`` plus 100 s windows, with
+    bursts past ``max_samples`` in one window, a stream no env knows
+    (``zz``) and empty envs. Items mix ``Record`` singles, multi-stream
+    batches and single-stream batches. ``ordered``: each stream's
+    timestamps never decrease in arrival order, across batches too, and
+    single-stream batches promise it (``sorted_ts=True``); otherwise rows
+    arrive shuffled and batches say ``None`` or ``False``."""
+    rng = np.random.RandomState(seed)
+    names = STREAMS + ["zz"]
+    last = np.full((E, len(names)), -np.inf)
+    calls = []
+    for c in range(n_calls):
+        lo, hi = T0 + c * K * WIN - 150.0, T0 + (c + 3) * K * WIN
+        per_env = []
+        for e in range(E):
+            if rng.rand() < 0.2:
+                per_env.append([])
+                continue
+            rows = []
+            for s, name in enumerate(names):
+                n = rng.randint(0, 14 * K)
+                ts = np.round(rng.uniform(max(lo, last[e, s]), hi, n)
+                              / 5) * 5 + 0.125 * rng.randint(0, 2, n)
+                if ordered:
+                    ts = np.sort(np.maximum(ts, last[e, s]))
+                    if n:
+                        last[e, s] = ts[-1]
+                rows += [Record(f"e{e}", name, float(t),
+                                float(rng.normal(5, 2))) for t in ts]
+            if ordered:
+                # interleave the streams, each keeping its own order
+                pick = rng.permutation(np.repeat(
+                    np.arange(len(names)),
+                    [sum(r.stream == nm for r in rows) for nm in names]))
+                per = {nm: iter([r for r in rows if r.stream == nm])
+                       for nm in names}
+                rows = [next(per[names[i]]) for i in pick]
+            else:
+                rows = [rows[i] for i in rng.permutation(len(rows))]
+            items, i = [], 0
+            while i < len(rows):
+                take = rows[i:i + 1 + rng.randint(10)]
+                i += len(take)
+                kind = rng.randint(3)
+                if kind == 0:
+                    items.extend(take)
+                elif kind == 1:
+                    items.append(RecordBatch.from_records(take))
+                else:
+                    for nm in names:
+                        mine = [r for r in take if r.stream == nm]
+                        if mine:
+                            items.append(RecordBatch.from_columns(
+                                f"e{e}", nm, [r.timestamp for r in mine],
+                                [r.value for r in mine],
+                                True if ordered else
+                                [None, False][rng.randint(2)]))
+            per_env.append(items)
+        calls.append(per_env)
+    return calls
+
+
+def _zeros(K, E, M):
+    shape = (K, E, len(STREAMS), M)
+    return (np.zeros(shape, np.float32), np.zeros(shape, np.float32),
+            np.zeros(shape, bool))
+
+
+@pytest.mark.parametrize("ordered", [True, False])
+@pytest.mark.parametrize("K", [1, 2, 8])
+def test_fleet_pass_matches_per_env_accumulators(K, ordered):
+    """Over four batches, the fleet pass and the per-env legacy
+    Accumulators agree byte for byte on the staging buffers, the
+    per-window counts and every env's stats; the carried records equal
+    the per-env pending ones. Ordered input never needs
+    the timestamp sort."""
+    E, M = 6, 4
+    calls = _fleet_calls(1000 + K, E, K, 4, ordered)
+    fleet = FleetAccumulator(STREAMS, M)
+    fleet_stats = {e: _new_stats() for e in range(E)}
+    per = [Accumulator(f"e{e}", STREAMS, M) for e in range(E)]
+    carried = fallbacks = 0
+    for c, per_env in enumerate(calls):
+        bounds = [(T0 + w * WIN, T0 + (w + 1) * WIN)
+                  for w in range(c * K, (c + 1) * K)]
+        starts = np.asarray([b[0] for b in bounds])
+        got = _zeros(K, E, M)
+        counts = fleet.ingest([(e, it) for e, it in enumerate(per_env) if it],
+                              starts, fleet_stats)
+        fleet.close_windows(bounds, got, fleet_stats)
+        carried += fleet.pending()
+        fallbacks += fleet.sort_fallback
+        ref = _zeros(K, E, M)
+        ref_counts = np.zeros(K, np.int64)
+        for e, items in enumerate(per_env):
+            ref_counts += _window_counts(items, starts)
+            per[e].ingest(items)
+            per[e].close_windows(bounds, rebase=True,
+                                 out=tuple(a[:, e] for a in ref))
+        for x, y in zip(got, ref):
+            assert x.dtype == y.dtype and x.tobytes() == y.tobytes()
+        assert (counts == ref_counts).all()
+        assert [fleet_stats[e] for e in range(E)] == [a.stats for a in per]
+        assert fleet.pending() == sum(a.pending() for a in per)
+    stats = [fleet_stats[e] for e in range(E)]
+    assert sum(s["overflow"] for s in stats) > 0
+    assert sum(s["unknown_stream"] for s in stats) > 0
+    assert carried > 0
+    if ordered:
+        assert fallbacks == 0
+    else:
+        assert fallbacks > 0
+
+
+@pytest.mark.parametrize("ts,fallback", [([10.0, 50.0], 0),
+                                         ([50.0, 10.0], 1)])
+def test_fleet_sort_fallback_counter(ts, fallback):
+    """``sort_fallback`` reads 1 exactly when a window's rows of one key
+    arrived out of timestamp order, and the close still orders them."""
+    fleet = FleetAccumulator(STREAMS, 4)
+    stats = {0: _new_stats()}
+    fleet.ingest([(0, [RecordBatch.from_columns("e", "temp_c", ts, ts)])],
+                 np.zeros(1), stats)
+    out = _zeros(1, 1, 4)
+    fleet.close_windows([(0.0, WIN)], out, stats)
+    assert fleet.sort_fallback == fallback
+    assert out[0][0, 0, 1, :2].tolist() == [10.0, 50.0]
+    assert stats[0]["records"] == 2 and fleet.pending() == 0
 
 
 # --------------------------------------------------------------------------
@@ -190,14 +391,15 @@ def test_fastpath_matches_legacy_per_ingest_path(ingest, protocols):
     assert _strip(ra) == _strip(rb)
 
 
-@pytest.mark.parametrize("mode", ["scan", "scan_async", "scan_fused_decide"])
-@pytest.mark.parametrize("workers", [2, 4])
-def test_ingest_workers_bit_identical(mode, workers):
-    """Worker-sharded assembly == serial assembly through the scan, async
-    (prefetcher epoch protocol) and fused-decide engines; the replay sink
-    sees identical rows."""
+@pytest.mark.parametrize("mode", ["scan", "scan_async", "scan_fused_decide",
+                                  "fused"])
+def test_fleet_pass_matches_legacy_per_mode(mode):
+    """The fleet pass == the per-env legacy Accumulators through the scan,
+    async (prefetcher epoch protocol), fused-decide and per-window
+    engines; the replay sink sees identical rows."""
     ref = _system(mode=mode)
-    got = _system(mode=mode, ingest_workers=workers)
+    got = _system(mode=mode, ingest_fastpath=False)
+    assert ref.fleet is not None and got.fleet is None
     rr, rg = ref.run_windows(7), got.run_windows(7)
     assert _strip(rr) == _strip(rg)
     ra, rb = ref.export_replay("s"), got.export_replay("s")

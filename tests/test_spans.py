@@ -5,7 +5,8 @@ a LogDB runs two batches under the profiler; the trace is read back with
 the benchmark's span loader (``bench/span_reduce.py``). Each span of the
 tree occurs once a batch, nested as documented; the assemble counters
 match what the queues and the staging buffers hold; and the results are
-bit-identical with the profiler on and off.
+bit-identical with the profiler on and off. Readings delivered a batch
+ahead make the fleet pass carry records, which ``carried`` reports.
 """
 import glob
 import os
@@ -23,6 +24,7 @@ from repro.runtime.db import LogDB
 from repro.runtime.forwarder import Forwarder, ForwarderHub
 from repro.runtime.predictor import ActionSpace, Predictor, linear_policy
 from repro.runtime.receivers import SimulatedDevice
+from repro.runtime.records import RecordBatch
 from repro.runtime.system import PerceptaSystem, SourceSpec
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "bench"))
@@ -69,13 +71,26 @@ def _dequeued(system):
     return sum(q["dequeued"] for q in system.broker.stats().values())
 
 
+def _deliver_ahead(system):
+    """One sorted batch of env b0's grid readings for the window after the
+    next batch, published straight to its queue: the next batch carries
+    it, the one after takes it."""
+    t0 = system.window_bounds(system.window_index + K)[0]
+    ts = t0 + np.arange(6) * 10.0
+    system.broker.publish(RecordBatch.from_columns("b0", "grid_kw", ts,
+                                                   ts / 100.0, True))
+
+
 def _run(tmp_path, name, trace):
-    """Warm up one batch, then run two more (traced when ``trace``).
-    Returns the rows, the sinks, the policy, the dequeued count before and
-    after each traced batch, and the trace's spans."""
+    """Warm up one batch, then run two more (traced when ``trace``), each
+    with readings delivered ahead. Returns the rows, the sinks, the
+    policy, the dequeued count before and after each traced batch, the
+    trace's spans, and the fleet's carried records and sort fallback after
+    each traced batch."""
     system = _system(str(tmp_path / f"{name}.db"))
     rows = system.run_windows(K)
     dequeued = [_dequeued(system)]
+    fleet = []
     if trace:
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
@@ -83,8 +98,11 @@ def _run(tmp_path, name, trace):
         assert spans.tracing()
     try:
         for _ in range(2):
+            _deliver_ahead(system)
             rows += system.run_windows(K)
             dequeued.append(_dequeued(system))
+            fleet.append((system.fleet.pending(),
+                          system.fleet.sort_fallback))
     finally:
         if trace:
             jax.profiler.stop_trace()
@@ -96,7 +114,7 @@ def _run(tmp_path, name, trace):
     sinks = [list(f.sink) for f in system.forwarders.forwarders]
     policy = jax.tree.map(np.asarray, system.snapshot_policy())
     system.stop()
-    return rows, sinks, policy, dequeued, found
+    return rows, sinks, policy, dequeued, found, fleet
 
 
 @pytest.fixture(scope="module")
@@ -129,9 +147,12 @@ def test_each_span_once_per_batch_and_nested(traced):
 
 
 def test_assemble_counters(traced):
-    found, dequeued = traced[4], traced[3]
+    found, dequeued, fleet = traced[4], traced[3], traced[5]
     assemble = sorted((x for x in found if x.name == "percepta.assemble"),
                       key=lambda x: x.start)
+    assert [(a.meta["carried"], a.meta["sort_fallbacks"])
+            for a in assemble] == fleet
+    assert fleet[0][0] >= 6            # the readings delivered ahead
     for j, a in enumerate(assemble):
         m = a.meta
         assert m["records"] == dequeued[j + 1] - dequeued[j] > 0
@@ -145,14 +166,30 @@ def test_assemble_counters(traced):
 
 
 def test_profiler_leaves_results_bit_identical(traced, tmp_path):
-    rows, sinks, policy, dequeued, _ = _run(tmp_path, "off", trace=False)
+    rows, sinks, policy, dequeued, _, fleet = _run(tmp_path, "off",
+                                                   trace=False)
     strip = lambda rs: [{k: v for k, v in r.items() if k != "latency_s"}
                         for r in rs]
     assert strip(rows) == strip(traced[0])
     assert sinks == traced[1]
     assert dequeued == traced[3]
+    assert fleet == traced[5]
     for a, b in zip(jax.tree.leaves(policy), jax.tree.leaves(traced[2])):
         np.testing.assert_array_equal(a, b)
+
+
+def test_untraced_assembly_computes_no_counter(tmp_path, monkeypatch):
+    """Without a profiler session the fleet's counters are never read."""
+    system = _system(str(tmp_path / "quiet.db"))
+
+    def boom():
+        raise AssertionError("counter computed while not tracing")
+
+    monkeypatch.setattr(system.fleet, "pending", boom)
+    assert not spans.tracing()
+    _deliver_ahead(system)
+    assert len(system.run_windows(K)) == K
+    system.stop()
 
 
 def test_fused_program_is_named(tmp_path):
